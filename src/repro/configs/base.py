@@ -189,8 +189,8 @@ class ParallelConfig:
     microbatches: int = 1
     # ---- kernel selection (threaded into kernels.backend.KernelConfig) ----
     # use_pallas: None = autodetect (pallas on TPU, jnp reference elsewhere);
-    # kernel_interpret: None = autodetect (compiled on TPU, interpret off-TPU,
-    # REPRO_KERNEL_INTERPRET env override); kernel_splits: split-K partitions
+    # kernel_interpret: None = autodetect (compiled on TPU, interpret
+    # off-TPU); kernel_splits: split-K partitions
     # of the decode page axis inside one kernel call.
     use_pallas: Optional[bool] = None
     kernel_interpret: Optional[bool] = None

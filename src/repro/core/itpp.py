@@ -26,9 +26,9 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from repro.core.jax_compat import shard_map
 from repro.core.paged_kv import merge_partials, partial_decode_attention
 from repro.kernels.backend import KernelConfig
 

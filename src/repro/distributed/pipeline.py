@@ -26,10 +26,10 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.itpp import ItppSpec, itpp_decode_attention_shard
-from repro.core.jax_compat import shard_map
 from repro.models import layers as L
 from repro.models import model as MDL
 from repro.models import moe as MOE

@@ -23,11 +23,11 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
+from jax.sharding import AbstractMesh
 from jax.sharding import PartitionSpec as P
 
 from repro.core.itpp import ItppSpec, make_itpp_attention
-from repro.core.jax_compat import abstract_mesh as _abstract_mesh
-from repro.core.jax_compat import shard_map
 from repro.models.model import Runtime
 from repro.models import moe as MOE
 
@@ -40,10 +40,9 @@ REPLICATE_SMALL = 1 << 16
 
 
 def abstract_mesh(shape, axis_names):
-    """Version-portable ``jax.sharding.AbstractMesh`` constructor (tests and
-    dry-run tooling build meshes through this so plan invariants can be
-    checked without real devices)."""
-    return _abstract_mesh(shape, axis_names)
+    """A device-free mesh (tests and dry-run tooling build meshes through
+    this so plan invariants can be checked without real devices)."""
+    return AbstractMesh(tuple(shape), tuple(axis_names))
 
 
 def _path_keys(path) -> list[str]:

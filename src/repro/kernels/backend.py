@@ -11,34 +11,26 @@ Resolution rules (``KernelConfig.resolve``):
   pure-jnp reference math IS the production path off-TPU — identical
   semantics, tested);
 * ``interpret=None``   -> False on TPU (compile via Mosaic), True elsewhere
-  (Pallas interpret mode for correctness tests on CPU), overridable with
-  the ``REPRO_KERNEL_INTERPRET`` env var (``1``/``0``).
+  (Pallas interpret mode for correctness tests on CPU). Nothing else
+  reaches it: on TPU a kernel runs interpreted only when its caller passes
+  ``interpret=True``.
 
 The dataclass is frozen/hashable so it can ride as a jit static argument
 and through ``functools.partial`` into shard_map bodies.
 """
 from __future__ import annotations
 
-import os
 from dataclasses import dataclass
 
 import jax
 
-_FALSY = ("0", "false", "no", "off", "")
-
 
 def on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # backend discovery can fail in exotic envs
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def default_interpret() -> bool:
-    """interpret=False on TPU, True elsewhere; REPRO_KERNEL_INTERPRET wins."""
-    env = os.environ.get("REPRO_KERNEL_INTERPRET")
-    if env is not None:
-        return env.strip().lower() not in _FALSY
+    """interpret=False on TPU, True elsewhere."""
     return not on_tpu()
 
 

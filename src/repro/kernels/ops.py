@@ -2,10 +2,10 @@
 
 Backend selection is automatic (``kernels/backend.py``): ``use_pallas=None``
 resolves to True on TPU and False elsewhere, and ``interpret=None`` resolves
-to False on TPU / True elsewhere (override with ``REPRO_KERNEL_INTERPRET``).
-Off-TPU production paths therefore lower the pure-jnp reference math while
-tests force ``interpret=True`` to exercise the kernels themselves — the
-semantics are identical (tests assert allclose).
+to False on TPU / True elsewhere. Off-TPU production paths therefore
+lower the pure-jnp reference math while tests force ``interpret=True`` to
+exercise the kernels themselves — the semantics are identical (tests
+assert allclose).
 
 Integration points:
   * ``paged_decode_step`` — THE decode hot path: the incoming token's K/V
@@ -32,8 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref as REF
-from repro.kernels.backend import (DEFAULT_KERNELS, KernelConfig,
-                                   default_interpret, on_tpu)
+from repro.kernels.backend import DEFAULT_KERNELS, KernelConfig, on_tpu
 from repro.kernels.flash_decode import flash_decode
 from repro.kernels.paged_attention import paged_attention
 from repro.kernels.ssm_scan import ssm_chunk_scan

@@ -8,20 +8,24 @@ exactly the paper's Dyn-Modi operand rewriting (§5.2) mapped onto Pallas.
 Two entry points:
 
 * ``paged_attention_partials`` — the decode hot path's shard-local compute
-  (``core/itpp.py``). Grid ``(n_splits, B, KVH, slots_per_split)``; each
-  split emits an UNNORMALIZED ``(o, l, m)`` partial, exactly the shape the
-  paper's §4.3 EPU aggregation merges across token partitions — so one
-  kernel serves both the cross-shard ITPP merge and flash-decoding-style
-  split-K parallelism on a single chip. The split-K axis LEADS the grid and
-  is declared ``parallel`` in the Mosaic ``dimension_semantics`` (parallel
-  axes must prefix the arbitrary ones), so megacore partitioning fans the
-  splits out across TensorCores instead of running them sequentially on
-  one — each (split, batch, head) owns its own scratch accumulation over
-  the trailing ``arbitrary`` slot axis, so the partition is race-free and
-  numerically identical. Nothing is gathered: K/V pages stream straight out
-  of the pool (the multi-step grid double-buffers the page stream — the
-  paper's ping-pong I/O, §6), replacing the gather-then-dense path's
-  [B, maxp, page, KVH, D] HBM materialization.
+  (``core/itpp.py``). Grid ``(n_splits, B, slots_per_split)``; each grid
+  step streams one whole ``[page, KVH, D]`` pool page (every KV head, so
+  each block spans the pool's trailing two dims as the TPU lowering
+  requires) and scores all heads in one masked block-diagonal MXU pass.
+  Each split emits an UNNORMALIZED ``(o, l, m)`` partial, exactly the
+  shape the paper's §4.3 EPU aggregation merges across token partitions —
+  so one kernel serves both the cross-shard ITPP merge and
+  flash-decoding-style split-K parallelism on a single chip. The split-K
+  axis LEADS the grid and is declared ``parallel`` in the Mosaic
+  ``dimension_semantics`` (parallel axes must prefix the arbitrary ones),
+  so megacore partitioning fans the splits out across TensorCores instead
+  of running them sequentially on one — each (split, batch) owns its own
+  scratch accumulation over the trailing ``arbitrary`` slot axis, so the
+  partition is race-free and numerically identical. Nothing is gathered:
+  K/V pages stream straight out of the pool (the multi-step grid
+  double-buffers the page stream — the paper's ping-pong I/O, §6),
+  replacing the gather-then-dense path's [B, maxp, page, KVH, D] HBM
+  materialization.
 * ``paged_attention`` — convenience full attention (partials merged and
   normalized), the single-shard kernel used by ``kernels/ops.py``.
 
@@ -41,7 +45,8 @@ Feature matrix (mirrors the gather-then-dense reference semantics):
   * ``windowed_slice`` the cond_window trick: the caller passes only the
                      table slots overlapping the window; slot ``j`` maps to
                      virtual page ``max(ctx-w,0)//page + j``,
-  * GQA ``G>=1``     q tile is [G, D] per kv head; K/V never repeated.
+  * GQA ``G>=1``     q tile is [KVH*G, D]; a row scores only its own
+                     head's columns; K/V never repeated.
 """
 from __future__ import annotations
 
@@ -65,7 +70,7 @@ def _partials_kernel(bt_ref, ctx_ref, w_ref,         # scalar prefetch
                      windowed_slice: bool, qpos: int = 1):
     s = pl.program_id(0)
     b = pl.program_id(1)
-    j = pl.program_id(3)
+    j = pl.program_id(2)
     slot = s * slots_per_split + j
 
     @pl.when(j == 0)
@@ -95,36 +100,48 @@ def _partials_kernel(bt_ref, ctx_ref, w_ref,         # scalar prefetch
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)                # [G*qpos, D]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)          # [page, D]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        d = q.shape[-1]
+        # every KV head in one MXU pass: the [page, KVH, D] block flattens
+        # to [page*KVH, D] (column c = token c // KVH of head c % KVH) and
+        # the score matrix is masked block-diagonal, so K/V stream straight
+        # from the [P, page, KVH, D] pool with no per-head slicing. The
+        # flatten goes through f32, whose (8, 128) tiling it keeps; the
+        # matmuls take the pool dtype, rounding p to it as the jnp
+        # reference does, and accumulate in f32.
+        kvh, d = k_ref.shape[2], k_ref.shape[3]
+        kv_dt = k_ref.dtype
+        q = q_ref[0].astype(kv_dt)                         # [KVH*R, D]
+        k = k_ref[0].astype(jnp.float32).reshape(page * kvh, d).astype(kv_dt)
+        v = v_ref[0].astype(jnp.float32).reshape(page * kvh, d).astype(kv_dt)
         rows = q.shape[0]
         sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        sc = sc / jnp.sqrt(jnp.float32(d))                 # [G*qpos, page]
-        tok = vp * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
-        # row r of the q tile is query position ctx-1 + (r % qpos): its
-        # effective context is ctx + r%qpos and its window slides with it
-        t_row = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) % qpos
-        hi = ctx + t_row                                   # [rows, 1]
+        sc = sc / jnp.sqrt(jnp.float32(d))                 # [KVH*R, page*KVH]
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, page * kvh), 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+        tok = vp * page + col // kvh
+        # row r of the q tile is head r // R at query position
+        # ctx-1 + (r % qpos): its effective context is ctx + r%qpos and its
+        # window slides with it
+        hi = ctx + row % qpos                              # [rows, 1]
         lo = jnp.where(w > 0, hi - w, 0)
-        ok = (tok < hi) & (tok >= lo)
+        ok = ((row // (rows // kvh) == col % kvh)
+              & (tok < hi) & (tok >= lo))
         sc = jnp.where(ok, sc, NEG_INF)
         m_prev = m_s[...]
-        m_new = jnp.maximum(m_prev, sc.max(axis=1))        # [G]
-        p = jnp.where(ok, jnp.exp(sc - m_new[:, None]), 0.0)
+        m_new = jnp.maximum(m_prev, sc.max(axis=1, keepdims=True))
+        p = jnp.where(ok, jnp.exp(sc - m_new), 0.0)
         corr = jnp.exp(m_prev - m_new)
-        l_s[...] = l_s[...] * corr + p.sum(axis=1)
-        acc_s[...] = acc_s[...] * corr[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        l_s[...] = l_s[...] * corr + p.sum(axis=1, keepdims=True)
+        acc_s[...] = acc_s[...] * corr + jax.lax.dot_general(
+            p.astype(kv_dt), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
         m_s[...] = m_new
 
     @pl.when(j == slots_per_split - 1)
     def _emit():
-        o_ref[0, 0, 0] = acc_s[...]
-        l_ref[0, 0, 0] = l_s[...]
-        m_ref[0, 0, 0] = m_s[...]
+        o_ref[0, 0] = acc_s[...]
+        l_ref[0, 0] = l_s[...]
+        m_ref[0, 0] = m_s[...]
 
 
 def paged_attention_partials(q, k_pages, v_pages, block_tables, ctx_lens, *,
@@ -163,62 +180,64 @@ def paged_attention_partials(q, k_pages, v_pages, block_tables, ctx_lens, *,
     w_arr = (jnp.zeros((B,), jnp.int32) if window is None else
              jnp.broadcast_to(jnp.asarray(window, jnp.int32).reshape(-1),
                               (B,)))
+    R = KVH * G                         # q rows: every head's G query rows
 
     # split-K axis first and ``parallel``: Mosaic requires parallel axes to
     # prefix arbitrary ones, and megacore partitioning then spreads the
-    # splits across cores (previously all splits ran sequentially per core
-    # — the ROADMAP n_splits>1 note). The trailing slot axis stays
-    # ``arbitrary``: it revisits the same (s, b, h) scratch accumulator.
-    grid = (S, B, KVH, K)
-    semantics = ("parallel", "arbitrary", "arbitrary", "arbitrary")
+    # splits across cores. The trailing slot axis stays ``arbitrary``: it
+    # revisits the same (s, b) scratch accumulator. Every block spans the
+    # array's last two dims, which the TPU lowering requires of any block
+    # not tiled by (8, 128); l/m carry a unit lane axis for the same reason.
+    grid = (S, B, K)
+    semantics = ("parallel", "arbitrary", "arbitrary")
 
-    def q_map(s, b, h, j, bt_ref, ctx_ref, w_ref):
-        return (b, h, 0, 0)
+    def q_map(s, b, j, bt_ref, ctx_ref, w_ref):
+        return (b, 0, 0)
 
-    def kv_map(s, b, h, j, bt_ref, ctx_ref, w_ref):
+    def kv_map(s, b, j, bt_ref, ctx_ref, w_ref):
         # dead slots clamp to page 0: the fetch is pipelined away when the
         # index repeats, and pl.when skips their compute either way
-        return (jnp.maximum(bt_ref[b, s * K + j], 0), 0, h, 0)
+        return (jnp.maximum(bt_ref[b, s * K + j], 0), 0, 0, 0)
 
-    def po_map(s, b, h, j, bt_ref, ctx_ref, w_ref):
-        return (s, b, h, 0, 0)
-
-    def pl_map(s, b, h, j, bt_ref, ctx_ref, w_ref):
-        return (s, b, h, 0)
+    def out_map(s, b, j, bt_ref, ctx_ref, w_ref):
+        return (s, b, 0, 0)
 
     kernel = functools.partial(_partials_kernel, page=page,
                                slots_per_split=K, ring_width=ring_width,
                                windowed_slice=windowed_slice, qpos=qpos)
-    return pl.pallas_call(
+    o, l, m = pl.pallas_call(
         kernel,
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=semantics),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, 1, G, D), q_map),
-                pl.BlockSpec((1, page, 1, D), kv_map),
-                pl.BlockSpec((1, page, 1, D), kv_map),
+                pl.BlockSpec((1, R, D), q_map),
+                pl.BlockSpec((1, page, KVH, D), kv_map),
+                pl.BlockSpec((1, page, KVH, D), kv_map),
             ],
             out_specs=[
-                pl.BlockSpec((1, 1, 1, G, D), po_map),
-                pl.BlockSpec((1, 1, 1, G), pl_map),
-                pl.BlockSpec((1, 1, 1, G), pl_map),
+                pl.BlockSpec((1, 1, R, D), out_map),
+                pl.BlockSpec((1, 1, R, 1), out_map),
+                pl.BlockSpec((1, 1, R, 1), out_map),
             ],
             scratch_shapes=[
-                pltpu.VMEM((G,), jnp.float32),      # m
-                pltpu.VMEM((G,), jnp.float32),      # l
-                pltpu.VMEM((G, D), jnp.float32),    # acc
+                pltpu.VMEM((R, 1), jnp.float32),    # m
+                pltpu.VMEM((R, 1), jnp.float32),    # l
+                pltpu.VMEM((R, D), jnp.float32),    # acc
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((S, B, KVH, G, D), jnp.float32),
-            jax.ShapeDtypeStruct((S, B, KVH, G), jnp.float32),
-            jax.ShapeDtypeStruct((S, B, KVH, G), jnp.float32),
+            jax.ShapeDtypeStruct((S, B, R, D), jnp.float32),
+            jax.ShapeDtypeStruct((S, B, R, 1), jnp.float32),
+            jax.ShapeDtypeStruct((S, B, R, 1), jnp.float32),
         ],
         interpret=resolve_interpret(interpret),
-    )(bt, ctx_lens.astype(jnp.int32), w_arr, q, k_pages, v_pages)
+    )(bt, ctx_lens.astype(jnp.int32), w_arr, q.reshape(B, R, D), k_pages,
+      v_pages)
+    return (o.reshape(S, B, KVH, G, D), l.reshape(S, B, KVH, G),
+            m.reshape(S, B, KVH, G))
 
 
 def paged_attention(q, k_pages, v_pages, block_tables, ctx_lens, *,

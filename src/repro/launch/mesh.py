@@ -5,7 +5,14 @@ touches jax device state — the dry-run sets XLA_FLAGS before first jax init.
 """
 from __future__ import annotations
 
-from repro.core.jax_compat import make_mesh
+import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto`` (jax defaults to
+    ``Explicit``): the plans here rely on sharding propagation."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -4,6 +4,13 @@ cache over a LongBench-like request trace.
   PYTHONPATH=src python -m repro.launch.serve --arch llama3.2-1b \
       --requests 16 --task musique --max-context 256
 
+runs the architecture's ``reduced()`` float32 twin (CPU-sized); ``--full``
+builds the published config at its own widths and dtype instead, e.g. on
+one TPU v5e:
+
+  PYTHONPATH=src python -m repro.launch.serve --arch internlm2-1.8b --full \
+      --requests 8 --slots 8 --pages 2048 --max-context 8192
+
 Reports achieved average batch (the paper's Fig. 4(b) metric), token
 throughput, host overhead, preemptions, and page-pool balance. ``--static``
 switches to baseline-PIM static allocation for the comparison;
@@ -43,8 +50,10 @@ zero extra work, zero extra device syncs.
 from __future__ import annotations
 
 import argparse
+import os
 import time
 from dataclasses import replace
+from pathlib import Path
 
 import numpy as np
 
@@ -73,15 +82,40 @@ def make_serve_telemetry(args):
     return make_telemetry(make_serve_tel_cfg(args))
 
 
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is set here. Otherwise the cache lives at one fixed path inside
+    the checkout (``.jax_cache``, ignored by git): the directory is part of
+    what a later run must find again, so it never carries a temporary name,
+    a process id or a time."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    path = str(Path(__file__).resolve().parents[3] / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def model_config(arch: str, full: bool, layers: int | None = None):
+    """The published config at its own widths and dtype (``full``), or its
+    ``reduced()`` float32 twin for CPU runs; ``layers`` cuts the depth."""
+    cfg = get_config(arch)
+    if full:
+        return cfg if layers is None else replace(cfg, n_layers=layers)
+    return replace(reduced(cfg, layers=layers), dtype="float32")
+
+
 def build_engine(args, telemetry=None) -> DecodeEngine:
-    cfg = replace(reduced(get_config(args.arch)), dtype="float32")
+    cfg = model_config(args.arch, args.full)
     draft_cfg = None
     if args.draft:
         # reduced() drafts share the reduced target's 256-token vocab, so
         # any attention-only arch pairs with any other; full-size cross-arch
         # pairs are vetted by validate_draft_pair at engine construction
-        draft_cfg = replace(reduced(get_config(args.draft), layers=1),
-                            dtype="float32")
+        draft_cfg = model_config(args.draft, args.full, layers=1)
     ecfg = EngineConfig(n_slots=args.slots, page_size=args.page,
                         n_pages=args.pages, max_context=args.max_context,
                         static_alloc=args.static, eos_token=-1,
@@ -147,7 +181,7 @@ def serve_cluster(args) -> int:
         raise SystemExit(f"--engines wants P+D (e.g. 1+1), "
                          f"got {args.engines!r}")
     tel_cfg = make_serve_tel_cfg(args)
-    cfg = replace(reduced(get_config(args.arch)), dtype="float32")
+    cfg = model_config(args.arch, args.full)
     ecfg = EngineConfig(n_slots=args.slots, page_size=args.page,
                         n_pages=args.pages, max_context=args.max_context,
                         static_alloc=args.static, eos_token=-1,
@@ -194,7 +228,8 @@ def serve_cluster(args) -> int:
     return done
 
 
-def submit_trace(eng: DecodeEngine, args) -> None:
+def submit_trace(eng: DecodeEngine, args) -> list[Request]:
+    """Submit ``args.requests`` LongBench-like requests; returns them."""
     rng = np.random.default_rng(0)
     # scale the LongBench length distribution into this toy max_context so
     # its VARIABILITY survives (clamping would park every prompt at the cap,
@@ -207,6 +242,7 @@ def submit_trace(eng: DecodeEngine, args) -> None:
     # the multi-tenant traffic shape radix prefix sharing pays off on
     system = rng.integers(0, eng.cfg.vocab_size,
                           size=args.max_context) if args.shared_frac else None
+    reqs = []
     for i, (plen, new) in enumerate(trace):
         plen = max(1, min(int(plen * factor),
                           args.max_context - new - 1))
@@ -214,10 +250,12 @@ def submit_trace(eng: DecodeEngine, args) -> None:
         if system is not None:
             k = min(int(plen * args.shared_frac), plen - 1)
             prompt[:k] = system[:k]
-        eng.submit(Request(i, prompt, new))
+        reqs.append(Request(i, prompt, new))
+        eng.submit(reqs[-1])
+    return reqs
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b")
     ap.add_argument("--requests", type=int, default=12)
@@ -228,7 +266,9 @@ def main(argv=None):
     ap.add_argument("--max-context", type=int, default=512)
     ap.add_argument("--mean-new", type=int, default=24)
     ap.add_argument("--static", action="store_true")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--full", action="store_true",
+                    help="serve the published config at its own widths and "
+                         "dtype instead of its reduced() float32 twin")
     ap.add_argument("--prefill-mode", default="batched",
                     choices=["slot", "batched", "chunked"])
     ap.add_argument("--chunk", type=int, default=32)
@@ -245,8 +285,8 @@ def main(argv=None):
                     help="host offload tier capacity in pages (0 = none)")
     ap.add_argument("--kernel", default="auto", choices=["auto", "on", "off"],
                     help="decode-attention pallas kernel path: auto = on "
-                         "TPU only (interpret autodetected via "
-                         "REPRO_KERNEL_INTERPRET)")
+                         "TPU only (compiled there, interpret mode "
+                         "elsewhere)")
     ap.add_argument("--kernel-splits", type=int, default=1,
                     help="split-K partitions of the page axis per kernel "
                          "call")
@@ -264,7 +304,7 @@ def main(argv=None):
                          "host sync per horizon); 1 = per-token dispatch")
     ap.add_argument("--draft", default="",
                     help="speculative decoding: arch name for a 1-layer "
-                         "reduced draft model proposing tokens the target "
+                         "draft model proposing tokens the target "
                          "verifies in one multi-query pass (greedy outputs "
                          "stay token-identical)")
     ap.add_argument("--spec-horizon", type=int, default=4,
@@ -332,7 +372,11 @@ def main(argv=None):
                         "(--disagg)")):
         ap.add_argument(flag, type=float, default=0.0,
                         help=f"per-decision injection probability: {kind}")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
+
+
+def main(argv=None):
+    args = parse_args(argv)
 
     if args.disagg:
         return serve_cluster(args)
@@ -419,4 +463,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

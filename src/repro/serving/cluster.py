@@ -120,10 +120,8 @@ class EngineCluster:
         self.ccfg = ccfg
         if params is None:
             import jax
-            import jax.numpy as jnp
             from repro.models import model as MDL
-            params = MDL.init_params(cfg, jax.random.PRNGKey(0),
-                                     jnp.float32)
+            params = MDL.init_params(cfg, jax.random.PRNGKey(0))
         self.params = params
         self.draft_params = draft_params
         self.faults = make_faults(ccfg.faults)

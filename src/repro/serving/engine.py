@@ -300,15 +300,16 @@ class DecodeEngine:
                     "distribution is known to the verifier; legacy per-row "
                     "sample= callables cannot be verified against")
             self.draft_cfg = dcfg
+        # params, pool and per-slot state all take the model config's dtype
         self.params = params if params is not None else MDL.init_params(
-            cfg, jax.random.PRNGKey(0), jnp.float32)
+            cfg, jax.random.PRNGKey(0))
         kinds = cfg.block_kinds()
         n_attn = cfg.n_layers if cfg.family == "encdec" else \
             sum(1 for k in kinds if k in ("attn", "local"))
         maxp = -(-ecfg.max_context // ecfg.page_size) + 1
         self.pool_spec = PoolSpec(
             max(n_attn, 1), ecfg.n_pages, ecfg.page_size, cfg.n_kv_heads,
-            cfg.d_head, maxp, dtype="float32")
+            cfg.d_head, maxp, dtype=cfg.dtype)
         static_pages = maxp if ecfg.static_alloc else None
         self.alloc = PageAllocator(
             ecfg.n_pages, ecfg.n_shards, ecfg.page_size, policy=ecfg.policy,
@@ -322,8 +323,7 @@ class DecodeEngine:
             n_rows=ecfg.n_rows, policy=make_policy(policy or ecfg.sched_policy),
             bt_width=self.pool_spec.max_pages_per_req)
         self.batcher.clock = self.clock
-        self.state = MDL.init_decode_state(cfg, self.pool_spec, ecfg.n_slots,
-                                           dtype="float32")
+        self.state = MDL.init_decode_state(cfg, self.pool_spec, ecfg.n_slots)
         self.tokens = np.zeros((ecfg.n_slots,), np.int32)
         self.prompts: dict[int, np.ndarray] = {}
         self.outputs: dict[int, list[int]] = {}
@@ -350,7 +350,7 @@ class DecodeEngine:
         # recurrent / cross-attention per-slot state rows ([L, n_slots, ...]
         # leaves of self.state) and their preemption snapshots
         self.has_rstate = bool(MDL.rstate_entries(self.state))
-        self._zero_rows = (MDL.init_rstate(cfg, 1, dtype="float32")
+        self._zero_rows = (MDL.init_rstate(cfg, 1)
                            if self.has_rstate else None)
         self.rsnaps: dict[int, dict] = {}   # req_id -> {len, rows, kv?}
         self.rstate_snapshots = 0
@@ -417,11 +417,11 @@ class DecodeEngine:
             # recomputes bit-identical rows.
             self.draft_spec = PoolSpec(
                 dcfg.n_layers, ecfg.n_pages, ecfg.page_size, dcfg.n_kv_heads,
-                dcfg.d_head, maxp, dtype="float32")
+                dcfg.d_head, maxp, dtype=dcfg.dtype)
             self.draft_params = draft_params if draft_params is not None \
-                else MDL.init_params(dcfg, jax.random.PRNGKey(1), jnp.float32)
+                else MDL.init_params(dcfg, jax.random.PRNGKey(1))
             self._dstate = MDL.init_decode_state(
-                dcfg, self.draft_spec, ecfg.n_slots, dtype="float32")
+                dcfg, self.draft_spec, ecfg.n_slots)
             self._dkey = jax.random.PRNGKey(ecfg.sample_seed + 1)
             # req_id -> tokens the draft pool has absorbed (its KV covers
             # positions [0, dlen)); reset to 0 at every (re)admission —
@@ -740,7 +740,7 @@ class DecodeEngine:
         """Encoder pass + cross-KV projection for enc-dec chunked prefill
         (stub zero frames, matching the slot path)."""
         frames = jnp.zeros((len(slots), self.cfg.enc_seq, self.cfg.d_model),
-                           jnp.float32)
+                           self.cfg.dtype)
         enc_out = MDL.encode(self.cfg, self.params, frames)
         ck, cv = MDL.make_cross_kv(self.cfg, self.params, enc_out)
         self.state = MDL.scatter_rstate(self.state, slots,

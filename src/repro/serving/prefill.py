@@ -58,7 +58,7 @@ def _make_batched_fn(cfg, rt):
 
     def fn(params, state, tokens, bt, last_idx, valid_len):
         frames = (jnp.zeros((tokens.shape[0], cfg.enc_seq, cfg.d_model),
-                            jnp.float32) if encdec else None)
+                            cfg.dtype) if encdec else None)
         return MDL.prefill(cfg, params, state, tokens, bt,
                            last_idx=last_idx, valid_len=valid_len,
                            frames=frames, rt=rt)
@@ -166,8 +166,7 @@ class SlotPrefiller:
         req.generated = 1              # prefill emits the first token
         prompt, emit = eng._prompt_seq(req)
         bt = eng.batcher.block_table_row(slot)
-        state1 = MDL.init_decode_state(eng.cfg, eng.pool_spec, 1,
-                                       dtype="float32")
+        state1 = MDL.init_decode_state(eng.cfg, eng.pool_spec, 1)
         # share the pool so pages written land in the engine pool
         if "pool" in eng.state:
             state1["pool"] = eng.state["pool"]
@@ -175,7 +174,7 @@ class SlotPrefiller:
             eng.cfg, eng.params, state1, jnp.asarray(prompt[None]),
             jnp.asarray(bt[None]), rt=eng.rt,
             frames=(jnp.zeros((1, eng.cfg.enc_seq, eng.cfg.d_model),
-                              jnp.float32)
+                              eng.cfg.dtype)
                     if eng.cfg.family == "encdec" else None))
         if "pool" in eng.state:
             eng.state["pool"] = state1["pool"]

@@ -17,7 +17,8 @@ def run_devprog(body: str, n_dev: int = 8):
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={n_dev}"
         import jax, jax.numpy as jnp, numpy as np
         jax.config.update("jax_platform_name", "cpu")
-        from repro.core.jax_compat import make_mesh, shard_map
+        from jax import shard_map
+        from repro.launch.mesh import make_mesh
         {textwrap.indent(textwrap.dedent(body), '        ').strip()}
         print("SUBPROC_OK")
     """)
@@ -161,10 +162,6 @@ def test_sharded_prefill_writer_matches_global():
 
 
 @pytest.mark.slow
-@pytest.mark.skipif(not hasattr(__import__("jax"), "shard_map"),
-                    reason="nested partial-manual shard_map needs the "
-                           "jax>=0.5 shard_map; 0.4.x SPMD partitioning "
-                           "rejects PartitionId inside the manual region")
 def test_pp_decode_matches_forward():
     """GPipe decode over the pod axis (nested ITPP+TP inside partial-manual
     shard_map) must equal the plain full-sequence forward."""

@@ -334,16 +334,16 @@ def test_flash_decode_tail_split():
 
 
 def test_backend_autodetect(monkeypatch):
-    """interpret defaults ride the backend; REPRO_KERNEL_INTERPRET wins."""
+    """Defaults ride the backend: on TPU the kernel compiles through
+    Mosaic unless the caller explicitly asks for interpret mode."""
     import repro.kernels.backend as BK
-    monkeypatch.delenv("REPRO_KERNEL_INTERPRET", raising=False)
     assert default_interpret() == (jax.default_backend() != "tpu")
-    monkeypatch.setenv("REPRO_KERNEL_INTERPRET", "0")
-    assert BK.default_interpret() is False
-    monkeypatch.setenv("REPRO_KERNEL_INTERPRET", "1")
-    assert BK.default_interpret() is True
     kc = KernelConfig().resolve()
-    assert kc.use_pallas == BK.on_tpu() and kc.interpret is True
+    assert kc.use_pallas == BK.on_tpu() and kc.interpret == (not BK.on_tpu())
+    monkeypatch.setattr(BK, "on_tpu", lambda: True)
+    kc = KernelConfig().resolve()
+    assert kc.use_pallas is True and kc.interpret is False
+    assert KernelConfig(interpret=True).resolve().interpret is True
 
 
 # ---------------------------------------------------------------------------

@@ -272,3 +272,24 @@ def test_engine_timing_reports_host_and_device_split():
     tm = eng.timing.as_dict()
     assert tm["steps"] > 0
     assert tm["decode_s"] > 0 and tm["host_s"] > 0
+
+
+def test_engine_takes_dtype_from_model_config():
+    """Params, pool and decode state follow the model config's dtype (the
+    published bf16 at full width), and serve.model_config builds the
+    published config only under ``full``."""
+    from repro.launch import serve
+    full = serve.model_config("internlm2-1.8b", True)
+    assert full == get_config("internlm2-1.8b") and full.dtype == "bfloat16"
+    assert serve.model_config("internlm2-1.8b", False).dtype == "float32"
+    assert serve.model_config("internlm2-1.8b", True, layers=1).n_layers == 1
+    cfg = replace(reduced(get_config("internlm2-1.8b")), dtype="bfloat16")
+    ecfg = EngineConfig(n_slots=2, page_size=PAGE, n_pages=32, max_context=24,
+                        eos_token=-1)
+    eng = DecodeEngine(cfg, ecfg)
+    assert {x.dtype for x in jax.tree.leaves(eng.params)} == \
+        {jnp.dtype(jnp.bfloat16)}
+    assert eng.state["pool"]["k"].dtype == jnp.bfloat16
+    eng.submit(Req(0, [1, 2, 3, 4, 5], 4))
+    eng.run(100)
+    assert len(eng.outputs[0]) == 5 and eng.alloc.pages_in_use == 0

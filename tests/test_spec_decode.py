@@ -10,6 +10,7 @@ through every prefill mode, EOS/budget truncation mid-round, preemption +
 resume, prefix sharing and a never-accepting draft (pure rollback).
 """
 import functools
+from dataclasses import replace
 
 import numpy as np
 import pytest
@@ -28,7 +29,7 @@ BUDGETS = [3, 12, 5, 12, 2, 9]
 
 @functools.lru_cache(maxsize=None)
 def _setup():
-    cfg = reduced(get_config("llama3.2-1b"))
+    cfg = replace(reduced(get_config("llama3.2-1b")), dtype="float32")
     params = MDL.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
     return cfg, params
 
@@ -37,7 +38,8 @@ def _setup():
 def _draft_setup():
     """A REAL small draft: 1 layer vs the target's 2, independent weights."""
     cfg, _ = _setup()
-    dcfg = reduced(get_config("llama3.2-1b"), layers=1)
+    dcfg = replace(reduced(get_config("llama3.2-1b"), layers=1),
+                   dtype="float32")
     dparams = MDL.init_params(dcfg, jax.random.PRNGKey(7), jnp.float32)
     return dcfg, dparams
 
@@ -308,7 +310,7 @@ def test_snapshot_async_drain():
     time); the drain converts them to host numpy within a tick. Outputs
     stay identical to the ample-pool run (covered by
     test_recurrent_prefill); here we pin the asynchrony itself."""
-    cfg = reduced(get_config("xlstm-350m"))
+    cfg = replace(reduced(get_config("xlstm-350m")), dtype="float32")
     params = MDL.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
     ecfg = EngineConfig(n_slots=2, page_size=4, n_pages=9, max_context=64,
                         eos_token=-1, prefill_mode="batched")
