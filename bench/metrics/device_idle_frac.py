@@ -1,0 +1,9 @@
+"""Device: share of the traced window in which no operation ran on the
+device (1 - union of operation intervals / window)."""
+
+
+def read(rec):
+    t = rec["trace"]
+    if not t or not t["window_s"]:
+        return None
+    return 1.0 - t["busy_s"] / t["window_s"]
