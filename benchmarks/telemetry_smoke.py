@@ -7,15 +7,16 @@ Prometheus endpoint), ``--trace-out`` and ``--request-log``, then
 * scrapes the live endpoint over real HTTP and runs the scraped text
   through the strict ``parse_exposition`` validator (per-tier PIM pool
   samples must be present);
-* loads the written chrome-trace JSON and runs ``validate_trace`` over it
-  (host/dispatch/sync engine tracks + the inferred device span must all be
-  there — the DCS-overlap picture Perfetto renders);
+* reads the captured ``jax.profiler`` trace: the engine's phase spans
+  (``engine.overlap`` / ``engine.collect`` / ``engine.dispatch`` / ...)
+  and the ops of its named programs (``jit_decode_horizon``) must both be
+  there, on the one clock Perfetto and xprof render;
 * parses the JSONL request records and cross-checks their token totals and
   finished-count against the scraped counters.
 
-Artifacts (``trace.json``, ``records.jsonl``, ``metrics.txt``) are left in
-``--outdir`` for CI upload so a failing run can be inspected in Perfetto /
-by eye. Exit code 0 = all formats valid.
+Artifacts (the ``trace/`` profiler directory, ``records.jsonl``,
+``metrics.txt``) are left in ``--outdir`` for CI upload so a failing run
+can be inspected in Perfetto / by eye. Exit code 0 = all formats valid.
 
 Usage::
 
@@ -32,21 +33,21 @@ import sys
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--outdir", default="tel_smoke",
-                    help="where trace.json / records.jsonl / metrics.txt land")
+                    help="where trace/ / records.jsonl / metrics.txt land")
     ap.add_argument("--requests", type=int, default=8)
     args = ap.parse_args(argv)
     os.makedirs(args.outdir, exist_ok=True)
-    trace_path = os.path.join(args.outdir, "trace.json")
+    trace_path = os.path.join(args.outdir, "trace")
     log_path = os.path.join(args.outdir, "records.jsonl")
     metrics_path = os.path.join(args.outdir, "metrics.txt")
 
     from repro.launch import serve
-    from repro.telemetry import parse_exposition, validate_trace
+    from repro.telemetry import parse_exposition
     from repro.telemetry import prom
-    from repro.telemetry.chrome_trace import ENGINE_PID, TRACKS
+    from repro.telemetry.profile import read_profile
 
     # small but non-trivial: preemption pressure (few pages), fused horizon
-    # (dispatch/sync/device tracks), chunked prefill
+    # (dispatch / collect phases), chunked prefill
     serve.main(["--requests", str(args.requests), "--slots", "3",
                 "--pages", "48", "--page", "8", "--max-context", "128",
                 "--mean-new", "12", "--prefill-mode", "chunked",
@@ -68,22 +69,26 @@ def main(argv=None) -> int:
                      "repro_pim_modeled_hbm_bytes_total",
                      "repro_pim_channel_util",
                      "repro_requests_finished_total",
-                     "repro_request_ttft_seconds_count"):
+                     "repro_request_ttft_seconds_count",
+                     "repro_engine_decode_steps_total",
+                     'repro_engine_compiles_total{program="decode_horizon"}',
+                     "repro_paged_grid_cells_total",
+                     "repro_paged_live_cells_total"):
         assert required in samples, f"missing sample {required}"
     assert samples["repro_requests_finished_total"] == args.requests
     print(f"[smoke] prometheus: {len(samples)} samples valid "
           f"({srv.url})")
 
-    # ---- chrome trace: load + validate tracks --------------------------
-    with open(trace_path) as f:
-        doc = json.load(f)
-    info = validate_trace(doc)
-    for track in ("host", "dispatch", "sync", "device"):
-        assert (ENGINE_PID, TRACKS[track]) in info["tracks"], \
-            f"missing engine track {track}"
-    assert info["slices"] > 0
-    print(f"[smoke] trace: {info['events']} events, {info['slices']} "
-          f"slices, {len(info['tracks'])} tracks -> {trace_path}")
+    # ---- profiler trace: engine phases and named programs --------------
+    spans, ops = read_profile(trace_path)
+    names = {n for n, _, _ in spans}
+    for phase in ("overlap", "collect", "fold", "schedule", "prefill",
+                  "config", "dispatch"):
+        assert f"engine.{phase}" in names, f"missing span engine.{phase}"
+    modules = {m for _, m, _, _ in ops}
+    assert "jit_decode_horizon" in modules, sorted(modules)
+    print(f"[smoke] trace: {len(spans)} engine spans, {len(ops)} ops of "
+          f"{len(modules)} programs -> {trace_path}")
 
     # ---- request records: JSONL parses, totals reconcile ---------------
     with open(log_path) as f:

@@ -90,12 +90,13 @@ def itpp_decode_attention_shard(q, k_new, v_new, pool_k, pool_v, block_table,
     my = _my_page_shard(spec, mesh_axis_sizes) if sharded else jnp.int32(0)
 
     # ---- 1. write the incoming token where owned --------------------------
-    owned_w = (new_page // P_loc) == my
-    loc_w = jnp.where(owned_w, new_page - my * P_loc, P_loc)     # OOB -> drop
-    pool_k = pool_k.at[loc_w, new_off].set(k_new.astype(pool_k.dtype),
-                                           mode="drop")
-    pool_v = pool_v.at[loc_w, new_off].set(v_new.astype(pool_v.dtype),
-                                           mode="drop")
+    with jax.named_scope("kv_write"):
+        owned_w = (new_page // P_loc) == my
+        loc_w = jnp.where(owned_w, new_page - my * P_loc, P_loc)  # OOB: drop
+        pool_k = pool_k.at[loc_w, new_off].set(
+            k_new.astype(pool_k.dtype), mode="drop")
+        pool_v = pool_v.at[loc_w, new_off].set(
+            v_new.astype(pool_v.dtype), mode="drop")
 
     owned = (block_table >= 0) & ((block_table // P_loc) == my)  # [B,maxp]
     vpage = jnp.broadcast_to(jnp.arange(maxp, dtype=jnp.int32)[None], (B, maxp))

@@ -234,6 +234,7 @@ def paged_attention_partials(q, k_pages, v_pages, block_tables, ctx_lens, *,
             jax.ShapeDtypeStruct((S, B, R, 1), jnp.float32),
         ],
         interpret=resolve_interpret(interpret),
+        name="paged_attention",
     )(bt, ctx_lens.astype(jnp.int32), w_arr, q.reshape(B, R, D), k_pages,
       v_pages)
     return (o.reshape(S, B, KVH, G, D), l.reshape(S, B, KVH, G),

@@ -40,12 +40,14 @@ restores) and backpressure (``--max-queue`` bounds the decode backlog).
 
 Telemetry (repro.telemetry, docs/observability.md): ``--metrics-port N``
 serves Prometheus text on ``http://127.0.0.1:N/metrics`` (0 = pick an
-ephemeral port, printed at startup), ``--trace-out trace.json`` writes a
-Perfetto/chrome://tracing timeline of the tick pipeline, ``--request-log
-records.jsonl`` exports one JSON record per finished request, and
-``--stats-every S`` prints a one-line summary every S seconds while
-serving. With none of these flags the telemetry layer is the shared no-op:
-zero extra work, zero extra device syncs.
+ephemeral port, printed at startup), ``--request-log records.jsonl``
+exports one JSON record per finished request, and ``--stats-every S``
+prints a one-line summary every S seconds while serving. With none of
+these flags the telemetry layer is the shared no-op: zero extra work, zero
+extra device syncs. ``--trace-out DIR`` captures a ``jax.profiler`` trace
+of the run into DIR: the engine's ``engine.<phase>`` spans on the host and
+the device ops of its named programs (``jit_decode_horizon``, ...), on one
+clock; it opens in Perfetto or xprof.
 """
 from __future__ import annotations
 
@@ -68,11 +70,9 @@ def make_serve_tel_cfg(args):
     flag is off."""
     from repro.telemetry import TelemetryConfig
     want_metrics = args.metrics_port >= 0 or args.stats_every > 0
-    if not (want_metrics or args.trace_out or args.request_log):
+    if not (want_metrics or args.request_log):
         return None
-    return TelemetryConfig(
-        metrics=True, trace_path=args.trace_out or None,
-        request_log=args.request_log or None)
+    return TelemetryConfig(metrics=True, request_log=args.request_log or None)
 
 
 def make_serve_telemetry(args):
@@ -317,8 +317,8 @@ def parse_args(argv=None) -> argparse.Namespace:
                     help="serve Prometheus text on this port (0 = "
                          "ephemeral, printed; -1 = off)")
     ap.add_argument("--trace-out", default="",
-                    help="write a Perfetto/chrome-trace JSON of the tick "
-                         "pipeline to this path")
+                    help="capture a jax.profiler trace of the run (engine "
+                         "phase spans and device ops) into this directory")
     ap.add_argument("--request-log", default="",
                     help="append one JSON record per finished request to "
                          "this path")
@@ -377,7 +377,18 @@ def parse_args(argv=None) -> argparse.Namespace:
 
 def main(argv=None):
     args = parse_args(argv)
+    if not args.trace_out:
+        return serve_engine(args)
+    import jax
+    jax.profiler.start_trace(args.trace_out)
+    try:
+        return serve_engine(args)
+    finally:
+        jax.profiler.stop_trace()
+        print(f"[serve] trace: {args.trace_out} (jax.profiler)", flush=True)
 
+
+def serve_engine(args):
     if args.disagg:
         return serve_cluster(args)
     tel = make_serve_telemetry(args)
@@ -454,10 +465,6 @@ def main(argv=None):
                   f"{sm['ttft_p50_ms']:.1f}/{sm['ttft_p90_ms']:.1f}/"
                   f"{sm['ttft_p99_ms']:.1f} ms  tpot p50 = "
                   f"{sm.get('tpot_p50_ms', 0):.2f} ms", flush=True)
-        n = tel.save_trace()
-        if n is not None:
-            print(f"[serve] trace: {args.trace_out} ({n} events)",
-                  flush=True)
         tel.close()
     return st.avg_batch
 

@@ -369,32 +369,50 @@ def train_loss(cfg, params, batch, rt: Runtime = DEFAULT_RT,
 # decode (single token, paged KV via ITPP)
 # ---------------------------------------------------------------------------
 
+# The decode program's compute scopes (``jax.named_scope``), as they show in
+# each compiled op's ``op_name`` metadata: a device op under none of them is
+# data movement (the layer scan's slices of the KV pool and of the stacked
+# weights, the pool's write-back, XLA's copies). ``layers`` marks the layer
+# scan itself.
+DECODE_SCOPES = ("embed", "attn", "kv_write", "paged_attn", "mlp", "head",
+                 "sample")
+
+
 def _attn_block_decode(p, cfg, x, cs, window, pool_k, pool_v, bt, ctx,
                        npage, noff, rt: Runtime, cross_kv=None):
-    """x [B, D] one token. Returns (x, pool_k, pool_v)."""
+    """x [B, D] one token. Returns (x, pool_k, pool_v). The named scopes
+    (``attn``, ``paged_attn`` with ``kv_write`` inside it, ``mlp``) put the
+    block's compute under those names in the compiled program's metadata;
+    see ``DECODE_SCOPES``."""
     B, D = x.shape
-    h = L.rms_norm(x[:, None, :], p["ln1"], cfg.norm_eps)
-    q, k, v = L.qkv_project(p["attn"], cfg, h)          # [B,1,H,dh]
-    if cs is not None:
-        q = L.apply_rope(q, *cs)
-        k = L.apply_rope(k, *cs)
-    a, pool_k, pool_v = rt.itpp_apply(
-        q[:, 0], k[:, 0], v[:, 0], pool_k, pool_v, bt, ctx, npage, noff, window)
-    x = x + L.dense(a.reshape(B, cfg.q_dim), p["attn"]["wo"])
-    if cross_kv is not None:
-        h = L.rms_norm(x[:, None, :], p["lnx"], cfg.norm_eps)
-        qx = L.dense(h, p["xattn"]["wq"]).reshape(B, cfg.n_heads, cfg.d_head)
-        kx, vx = cross_kv
-        ax = L.decode_attention_ref(
-            qx, kx, vx, jnp.full((B,), kx.shape[1], jnp.int32))
-        x = x + L.dense(ax.reshape(B, cfg.q_dim), p["xattn"]["wo"])
+    with jax.named_scope("attn"):
+        h = L.rms_norm(x[:, None, :], p["ln1"], cfg.norm_eps)
+        q, k, v = L.qkv_project(p["attn"], cfg, h)          # [B,1,H,dh]
+        if cs is not None:
+            q = L.apply_rope(q, *cs)
+            k = L.apply_rope(k, *cs)
+    with jax.named_scope("paged_attn"):
+        a, pool_k, pool_v = rt.itpp_apply(
+            q[:, 0], k[:, 0], v[:, 0], pool_k, pool_v, bt, ctx, npage, noff,
+            window)
+    with jax.named_scope("attn"):
+        x = x + L.dense(a.reshape(B, cfg.q_dim), p["attn"]["wo"])
+        if cross_kv is not None:
+            h = L.rms_norm(x[:, None, :], p["lnx"], cfg.norm_eps)
+            qx = L.dense(h, p["xattn"]["wq"]).reshape(B, cfg.n_heads,
+                                                     cfg.d_head)
+            kx, vx = cross_kv
+            ax = L.decode_attention_ref(
+                qx, kx, vx, jnp.full((B,), kx.shape[1], jnp.int32))
+            x = x + L.dense(ax.reshape(B, cfg.q_dim), p["xattn"]["wo"])
     if "ln2" in p:
-        h2 = L.rms_norm(x[:, None, :], p["ln2"], cfg.norm_eps)
-        if "moe" in p:
-            y, _ = rt.moe_apply(p["moe"], cfg, h2)
-        else:
-            y = L.mlp(p["mlp"], h2, cfg.act)
-        x = x + y[:, 0]
+        with jax.named_scope("mlp"):
+            h2 = L.rms_norm(x[:, None, :], p["ln2"], cfg.norm_eps)
+            if "moe" in p:
+                y, _ = rt.moe_apply(p["moe"], cfg, h2)
+            else:
+                y = L.mlp(p["mlp"], h2, cfg.act)
+            x = x + y[:, 0]
     return rt.constrain(x, "act_decode"), pool_k, pool_v
 
 
@@ -502,15 +520,16 @@ def decode_step(cfg, params, state, tokens, bt, ctx, npage, noff, *,
     Returns (fp32 logits [B, V], new_state).
     """
     B = tokens.shape[0]
-    x = L.embed(params["embed"], tokens)                # [B, D]
-    if cfg.rope_kind == "none" and cfg.family == "encdec":
-        x = x + L.sinusoidal_at(ctx - 1, cfg.d_model).astype(x.dtype)
-    if positions is None:
-        pos = (ctx - 1).astype(jnp.int32)[:, None]      # [B,1]
-        if cfg.rope_kind == "mrope":
-            pos = jnp.broadcast_to(pos[None], (3, B, 1))
-        positions = pos
-    cs = _cos_sin(cfg, positions)
+    with jax.named_scope("embed"):
+        x = L.embed(params["embed"], tokens)            # [B, D]
+        if cfg.rope_kind == "none" and cfg.family == "encdec":
+            x = x + L.sinusoidal_at(ctx - 1, cfg.d_model).astype(x.dtype)
+        if positions is None:
+            pos = (ctx - 1).astype(jnp.int32)[:, None]  # [B,1]
+            if cfg.rope_kind == "mrope":
+                pos = jnp.broadcast_to(pos[None], (3, B, 1))
+            positions = pos
+        cs = _cos_sin(cfg, positions)
     x = rt.constrain(x, "act_decode")
     kinds = cfg.block_kinds()
     state = dict(state)
@@ -540,7 +559,8 @@ def decode_step(cfg, params, state, tokens, bt, ctx, npage, noff, *,
         xs = ((stack, windows, pool["k"], pool["v"],
                state["cross_k"], state["cross_v"])
               if has_cross else (stack, windows, pool["k"], pool["v"]))
-        x, (pk, pv) = jax.lax.scan(body, x, xs)
+        with jax.named_scope("layers"):
+            x, (pk, pv) = jax.lax.scan(body, x, xs)
         state["pool"] = {"k": pk, "v": pv}
     elif "mlstm" in params:
         def body(carry, xs):
@@ -585,9 +605,10 @@ def decode_step(cfg, params, state, tokens, bt, ctx, npage, noff, *,
             jax.tree.map(lambda *xs: jnp.concatenate(xs, 0), *new_mamba),
             state["mamba"], run)
 
-    x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    w = params["embed"] if cfg.tie_embeddings else params["head"]
-    logits = L.lm_head(x, w, transpose=cfg.tie_embeddings)
+    with jax.named_scope("head"):
+        x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
+        w = params["embed"] if cfg.tie_embeddings else params["head"]
+        logits = L.lm_head(x, w, transpose=cfg.tie_embeddings)
     return rt.constrain(logits, "logits_decode"), state
 
 
@@ -630,24 +651,26 @@ def decode_multi(cfg, params, state, tokens, bt, ctx, rem, allow, key, *,
 
     def body(carry, _):
         tokens, ctx, rem, allow, alive, state, key = carry
-        run = alive & (allow > 0)
-        npage, noff = write_targets(bt, ctx, run, page_size=page_size,
-                                    n_pages=n_pages,
-                                    ring_width=rt.ring_width)
+        with jax.named_scope("kv_write"):
+            run = alive & (allow > 0)
+            npage, noff = write_targets(bt, ctx, run, page_size=page_size,
+                                        n_pages=n_pages,
+                                        ring_width=rt.ring_width)
         logits, state = decode_step(cfg, params, state, tokens, bt_attn,
                                     ctx, npage, noff, run=run, rt=rt)
-        key, sub = jax.random.split(key)
-        nxt = sample(sub, logits, run) if sample_takes_run \
-            else sample(sub, logits)
-        tokens = jnp.where(run, nxt, tokens)
-        rem = jnp.where(run, rem - 1, rem)
-        fin = run & ((nxt == eos_token) | (rem <= 0))
-        alive = alive & ~fin
-        # finished slots freeze at their final context; paused (allow
-        # spent) and running slots advance so the pending token's write
-        # position is ready for the next step/horizon
-        ctx = jnp.where(run & ~fin, ctx + 1, ctx)
-        allow = jnp.where(run, allow - 1, allow)
+        with jax.named_scope("sample"):
+            key, sub = jax.random.split(key)
+            nxt = sample(sub, logits, run) if sample_takes_run \
+                else sample(sub, logits)
+            tokens = jnp.where(run, nxt, tokens)
+            rem = jnp.where(run, rem - 1, rem)
+            fin = run & ((nxt == eos_token) | (rem <= 0))
+            alive = alive & ~fin
+            # finished slots freeze at their final context; paused (allow
+            # spent) and running slots advance so the pending token's write
+            # position is ready for the next step/horizon
+            ctx = jnp.where(run & ~fin, ctx + 1, ctx)
+            allow = jnp.where(run, allow - 1, allow)
         return (tokens, ctx, rem, allow, alive, state, key), (nxt, run)
 
     alive0 = allow > 0

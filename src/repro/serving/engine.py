@@ -134,11 +134,12 @@ class EngineConfig:
     state_resume: bool = True
     # ---- telemetry (repro.telemetry) ----
     # None (default) = the shared no-op facade: no registry, no scheduler
-    # events hook, no trace buffer — behavior and device-sync count are
-    # identical to a build without telemetry. A TelemetryConfig (or an
-    # already-built Telemetry, e.g. serve.py's) turns on the metrics
-    # registry / per-request tracing / Perfetto tick timeline; all of it is
-    # host-side bookkeeping riding the existing horizon readback.
+    # events hook — behavior and device-sync count are identical to a
+    # build without telemetry. A TelemetryConfig (or an already-built
+    # Telemetry, e.g. serve.py's) turns on the metrics registry and
+    # per-request tracing; all of it is host-side bookkeeping riding the
+    # existing horizon readback. The engine's phases are profiler spans
+    # either way (``_phase``).
     telemetry: Any = None
     # ---- fault injection (repro.runtime.faults) ----
     # None (default) = the shared no-op injector: fire() short-circuits on
@@ -162,7 +163,7 @@ class EngineConfig:
     # timestamps, the scheduler's SLO policies and the request tracker all
     # read THIS clock, so a runtime.clock.VirtualClock makes trace replay
     # and deadline expiry fully deterministic. Performance accounting
-    # (EngineTiming, Perfetto slices) stays on the wall clock regardless —
+    # (EngineTiming, profiler spans) stays on the wall clock regardless —
     # it measures the machine, not the workload.
     clock: Any = None
     # ---- graceful degradation ----
@@ -204,13 +205,36 @@ class EngineConfig:
 
 @dataclass
 class EngineTiming:
-    """Wall-clock split of the serving loop (host bookkeeping vs device)."""
+    """Wall-clock split of the serving loop (host bookkeeping vs device),
+    and counts of the decode work dispatched and of the programs compiled."""
     steps: int = 0
     host_s: float = 0.0               # schedule + config-buffer assembly
     prefill_s: float = 0.0
     decode_s: float = 0.0             # compiled decode step + sampling
     device_syncs: int = 0             # host<->device decode rendezvous
     decode_tokens: int = 0            # tokens emitted by decode dispatches
+    # decode steps dispatched (a fused horizon's scan steps, or step()'s
+    # one; speculative rounds are not counted), the paged kernel's grid
+    # over them, n_slots x table width cells a step, and the cells that
+    # hold live KV: a running slot at step j of a dispatch with context
+    # ctx has ceil((ctx + j) / page) live pages
+    decode_steps: int = 0
+    paged_grid_cells: int = 0
+    paged_live_cells: int = 0
+    # program name -> specializations the engine's calls added to its jit
+    # cache (a compile, or a load from the persistent compile cache)
+    compiles: dict = field(default_factory=dict)
+
+    @contextmanager
+    def compiling(self, fn):
+        """Count what the calls of jitted ``fn`` inside the block add to
+        its cache, under the program's name."""
+        n = fn._cache_size()
+        yield
+        grown = fn._cache_size() - n
+        if grown > 0:                 # (a cleared cache shrinks it)
+            self.compiles[fn.__name__] = \
+                self.compiles.get(fn.__name__, 0) + grown
 
     def as_dict(self) -> dict:
         n = max(1, self.steps)
@@ -219,7 +243,11 @@ class EngineTiming:
                 "host_s": self.host_s, "device_syncs": self.device_syncs,
                 "decode_tokens": self.decode_tokens,
                 "syncs_per_token": self.device_syncs
-                / max(1, self.decode_tokens)}
+                / max(1, self.decode_tokens),
+                "decode_steps": self.decode_steps,
+                "paged_grid_cells": self.paged_grid_cells,
+                "paged_live_cells": self.paged_live_cells,
+                "compiles": dict(self.compiles)}
 
 
 class DeviceSlotState:
@@ -242,11 +270,11 @@ class DeviceSlotState:
         self.rem = jnp.zeros((n_slots,), jnp.int32)
         self.key = jax.random.PRNGKey(seed)
         self._patch = jax.jit(
-            DeviceSlotState._patch_fn,
+            DeviceSlotState.patch_slots,
             donate_argnums=(0, 1, 2, 3) if donate else ())
 
     @staticmethod
-    def _patch_fn(bt, ctx, tok, rem, idx, bt_rows, ctx_v, tok_v, rem_v):
+    def patch_slots(bt, ctx, tok, rem, idx, bt_rows, ctx_v, tok_v, rem_v):
         return (bt.at[idx].set(bt_rows), ctx.at[idx].set(ctx_v),
                 tok.at[idx].set(tok_v), rem.at[idx].set(rem_v))
 
@@ -469,30 +497,39 @@ class DecodeEngine:
         from repro.telemetry import make_telemetry
         self.tel = make_telemetry(ecfg.telemetry)
         self.tel.attach_engine(self)
-        # (dispatch wall-clock, dispatch-time ctx snapshot, horizon seq) of
-        # the in-flight horizon — feeds the inferred device span and the
+        # dispatch-time ctx snapshot of the in-flight horizon — feeds the
         # modeled-bytes counter at collect; stays None when tel is off
-        self._horizon_meta: tuple | None = None
-        self._horizon_seq = 0
+        self._horizon_ctx: np.ndarray | None = None
 
-    # ---- unified timing/trace phase helper ----------------------------
+    # ---- unified timing phase helper -----------------------------------
     @contextmanager
-    def _phase(self, acc: str, track: str | None = None,
-               name: str | None = None):
+    def _phase(self, acc: str, name: str):
         """Accumulate one timed segment into ``EngineTiming.<acc>`` —
         the SINGLE bookkeeping path both ``step()`` and the fused tick use,
         so host/prefill/decode splits stay consistent when the APIs
-        interleave — and, when tracing, emit the segment as a Perfetto
-        slice on ``track``."""
+        interleave — inside a profiler span ``engine.<name>``. With no
+        profiler active the span costs about a microsecond; under
+        ``jax.profiler`` it puts the segment on the device trace's clock,
+        beside the device ops it waits for or dispatches."""
         t0 = time.perf_counter()
         try:
-            yield
+            with jax.profiler.TraceAnnotation(f"engine.{name}"):
+                yield
         finally:
             dt = time.perf_counter() - t0
             setattr(self.timing, acc, getattr(self.timing, acc) + dt)
-            tr = self.tel.trace
-            if tr is not None and track is not None:
-                tr.slice(track, name or acc, t0, dt)
+
+    def _count_decode_work(self, allow: np.ndarray, steps: int,
+                           width: int) -> None:
+        """Fold one decode dispatch into the work counters: ``steps``
+        decode steps over the ``width``-page table, slot s running the
+        first ``allow[s]`` of them from its dispatch-time context."""
+        t, page = self.timing, self.ecfg.page_size
+        t.decode_steps += steps
+        t.paged_grid_cells += steps * self.ecfg.n_slots * width
+        j = np.arange(steps)
+        live = -(-(self.batcher._ctx[:, None] + j[None, :]) // page)
+        t.paged_live_cells += int(live[j[None, :] < allow[:, None]].sum())
 
     # ------------------------------------------------------------------
     def submit(self, req: RequestSpec | int, prompt: np.ndarray = None,
@@ -927,7 +964,7 @@ class DecodeEngine:
         device mirror must re-sync before the next horizon), and the
         returned mask is also stashed for a later ``run()``."""
         E = self.ecfg
-        with self._phase("host_s", "host", "schedule"):
+        with self._phase("host_s", "schedule"):
             self._drain_snapshots()
             if self._pending_fin is not None:
                 finished_mask = self._pending_fin if finished_mask is None \
@@ -948,14 +985,14 @@ class DecodeEngine:
                     self.state["pool"] = self.cache.apply_pending(
                         self.state["pool"])
         if admitted or self.prefiller.busy:
-            with self._phase("prefill_s", "prefill", "prefill"):
+            with self._phase("prefill_s", "prefill"):
                 active = self.prefiller.run(admitted, active)
         self.timing.steps += 1
         if not active:
             return np.zeros((E.n_slots,), bool)
 
         # ---- config-buffer assembly, vectorized over slots ------------
-        with self._phase("host_s", "host", "config"):
+        with self._phase("host_s", "config"):
             ctx = self.batcher.context_lens()
             bt = self.batcher.block_tables(self.pool_spec.max_pages_per_req)
             W = self.pool_spec.max_pages_per_req
@@ -983,16 +1020,20 @@ class DecodeEngine:
                 bt = bt[:, :decode_table_bucket(self.batcher.max_live_pages(),
                                                 W)]
             if self._decode_jit is None:
-                def fn(params, state, tokens, bt, ctx, npage, noff, run):
+                def decode_step(params, state, tokens, bt, ctx, npage, noff,
+                                run):
                     return MDL.decode_step(self.cfg, params, state, tokens,
                                            bt, ctx, npage, noff, run=run,
                                            rt=self.rt)
-                self._decode_jit = jax.jit(fn)
+                self._decode_jit = jax.jit(decode_step)
+            self._count_decode_work(active_mask.astype(np.int32), 1,
+                                    bt.shape[1])
 
         # ``run`` masks the recurrent-state advance: idle and mid-chunk-
         # prefill slots must not absorb their stale pending token (their
         # attention KV writes already drop via the out-of-bounds npage)
-        with self._phase("decode_s", "sync", "decode+sample"):
+        with self._phase("decode_s", "decode_sample"), \
+                self.timing.compiling(self._decode_jit):
             logits, self.state = self._decode_jit(
                 self.params, self.state, jnp.asarray(self.tokens),
                 jnp.asarray(bt), jnp.asarray(ctx), jnp.asarray(npage),
@@ -1006,7 +1047,7 @@ class DecodeEngine:
                 nxt = self._sample_rows(logits)
 
         # ---- EOS / budget bookkeeping, vectorized ----------------------
-        with self._phase("host_s", "host", "bookkeep"):
+        with self._phase("host_s", "bookkeep"):
             # invalid-logits quarantine (this path sees the real host
             # logits): a non-finite row — or an injected NaN plan — means
             # the sample is garbage; the token is not emitted and the
@@ -1059,8 +1100,8 @@ class DecodeEngine:
             sample = make_scan_sampler(E.sampler, temperature=E.temperature,
                                        top_k=E.top_k)
 
-        def fn(params, state, tokens, bt, ctx, rem, allow, key, *,
-               horizon, width):
+        def decode_horizon(params, state, tokens, bt, ctx, rem, allow, key,
+                           *, horizon, width):
             return MDL.decode_multi(
                 cfg, params, state, tokens, bt, ctx, rem, allow, key,
                 horizon=horizon, table_width=width, page_size=E.page_size,
@@ -1068,7 +1109,7 @@ class DecodeEngine:
                 rt=rt)
 
         donate = (1, 2, 4, 5, 7) if self._donate else ()
-        return jax.jit(fn, static_argnames=("horizon", "width"),
+        return jax.jit(decode_horizon, static_argnames=("horizon", "width"),
                        donate_argnums=donate)
 
     # ---- speculative decode: propose / catch-up / verify ----------------
@@ -1089,15 +1130,15 @@ class DecodeEngine:
                                  top_k=E.top_k)
         need_q = E.sampler != "greedy"
 
-        def propose(dparams, dstate, tokens, bt, ctx, allow, dkey, *,
-                    horizon, width):
+        def spec_propose(dparams, dstate, tokens, bt, ctx, allow, dkey, *,
+                         horizon, width):
             return MDL.draft_propose(
                 dcfg, dparams, dstate, tokens, bt, ctx, allow, dkey,
                 horizon=horizon, table_width=width, page_size=E.page_size,
                 n_pages=E.n_pages, sample=sample, need_q=need_q, rt=rt)
 
-        def verify(params, state, tokens, proposals, qlogits, bt, ctx, rem,
-                   allow, key, *, horizon, width):
+        def spec_verify(params, state, tokens, proposals, qlogits, bt, ctx,
+                        rem, allow, key, *, horizon, width):
             return MDL.decode_verify(
                 self.cfg, params, state, tokens, proposals, qlogits, bt,
                 ctx, rem, allow, key, horizon=horizon, table_width=width,
@@ -1106,9 +1147,9 @@ class DecodeEngine:
 
         dp = (1, 6) if self._donate else ()
         dv = (1, 2, 4, 6, 7, 9) if self._donate else ()
-        return (jax.jit(propose, static_argnames=("horizon", "width"),
+        return (jax.jit(spec_propose, static_argnames=("horizon", "width"),
                         donate_argnums=dp),
-                jax.jit(verify, static_argnames=("horizon", "width"),
+                jax.jit(spec_verify, static_argnames=("horizon", "width"),
                         donate_argnums=dv))
 
     def _draft_catchup(self, active) -> None:
@@ -1158,10 +1199,11 @@ class DecodeEngine:
         # writes drop, exactly like group-prefill end padding
         for i in range(len(needy), rows):
             bt_rows[i] = bt_rows[len(needy) - 1]
-        _, dstate = self._catchup_jit(
-            self.draft_params, {"pool": self._dstate["pool"]},
-            jnp.asarray(toks), jnp.asarray(bt_rows), jnp.asarray(starts),
-            jnp.asarray(np.maximum(lens - 1, 0)), jnp.asarray(lens))
+        with self.timing.compiling(self._catchup_jit):
+            _, dstate = self._catchup_jit(
+                self.draft_params, {"pool": self._dstate["pool"]},
+                jnp.asarray(toks), jnp.asarray(bt_rows), jnp.asarray(starts),
+                jnp.asarray(np.maximum(lens - 1, 0)), jnp.asarray(lens))
         self._dstate["pool"] = dstate["pool"]
 
     def _dispatch_spec(self, active, allow, K: int, width: int) -> None:
@@ -1172,14 +1214,17 @@ class DecodeEngine:
         propose, verify = self._spec_jits
         G = K - 1
         allow_j = jnp.asarray(allow)
-        prop, qlog, self._dstate, self._dkey = propose(
-            self.draft_params, self._dstate, self.dev.tokens, self.dev.bt,
-            self.dev.ctx, allow_j, self._dkey, horizon=G, width=width)
-        toks, emit, fin, self.state, self.dev.tokens, self.dev.ctx, \
-            self.dev.rem, self.dev.key, acc = verify(
-                self.params, self.state, self.dev.tokens, prop, qlog,
-                self.dev.bt, self.dev.ctx, self.dev.rem, allow_j,
-                self.dev.key, horizon=G, width=width)
+        with self.timing.compiling(propose):
+            prop, qlog, self._dstate, self._dkey = propose(
+                self.draft_params, self._dstate, self.dev.tokens,
+                self.dev.bt, self.dev.ctx, allow_j, self._dkey, horizon=G,
+                width=width)
+        with self.timing.compiling(verify):
+            toks, emit, fin, self.state, self.dev.tokens, self.dev.ctx, \
+                self.dev.rem, self.dev.key, acc = verify(
+                    self.params, self.state, self.dev.tokens, prop, qlog,
+                    self.dev.bt, self.dev.ctx, self.dev.rem, allow_j,
+                    self.dev.key, horizon=G, width=width)
         nprop = np.clip(allow - 1, 0, G).astype(np.int32)
         self._inflight = (toks, emit, fin,
                           [(s, self.batcher.slots[s]) for s in active],
@@ -1202,8 +1247,9 @@ class DecodeEngine:
             req = self.batcher.slots[s]
             if req is not None and req.prefill_done:
                 rem_v[i] = max(0, req.max_new_tokens - req.generated + 1)
-        self.dev.patch(dirty, rows, ctx_v.astype(np.int32),
-                       tok_v.astype(np.int32), rem_v)
+        with self.timing.compiling(self.dev._patch):
+            self.dev.patch(dirty, rows, ctx_v.astype(np.int32),
+                           tok_v.astype(np.int32), rem_v)
 
     def _collect_horizon(self):
         """Sync point: block on the in-flight horizon's token readback (the
@@ -1213,70 +1259,68 @@ class DecodeEngine:
             return None
         toks, emit, fin, pairs, spec = self._inflight
         self._inflight = None
-        meta, self._horizon_meta = self._horizon_meta, None
-        with self._phase("decode_s", "sync", "collect"):
+        hctx, self._horizon_ctx = self._horizon_ctx, None
+        with self._phase("decode_s", "collect"):
             toks, emit, fin = (np.asarray(toks), np.asarray(emit),
                                np.asarray(fin))
             acc = np.asarray(spec[0]) if spec is not None else None
-        self.timing.device_syncs += 1
-        tel = self.tel.enabled
-        if tel and meta is not None and self.tel.trace is not None:
-            # the horizon's device-busy window, inferred dispatch->readback:
-            # an async span so overlapping host slices stay visible
-            self.tel.trace.span("device", "horizon", meta[2], meta[0],
-                                time.perf_counter(),
-                                args={"slots": len(pairs)})
-        # one readback timestamp for the whole horizon: every emission in
-        # it became host-visible at the same sync, and the per-request
-        # records must reproduce the first_tok_t-based TTFT exactly
-        tnow = self.clock()
-        tok_ctx = 0.0
-        finished = np.zeros((self.ecfg.n_slots,), bool)
-        for slot, req in pairs:
-            ts = toks[emit[:, slot], slot]
-            if not len(ts):            # pool-starved to zero steps
-                continue
-            # invalid-logits quarantine: an injected NaN plan, or sampled
-            # ids outside the logits width (the fused path cannot see the
-            # device-side logits, so garbage shows up as out-of-range ids).
-            # The horizon's tokens are NOT folded — the request goes
-            # terminal at this tick's safe point with reason "nan"
-            if (self.faults.enabled
-                    and self.faults.fire("nan_logits", key=req.req_id)) \
-                    or (self.nan_guard
-                        and (int(ts.min()) < 0
-                             or int(ts.max()) >= self.cfg.padded_vocab)):
-                self._abort_req.setdefault(req.req_id, "nan")
-                continue
-            self.outputs[req.req_id].extend(int(t) for t in ts)
-            self.first_tok_t.setdefault(req.req_id, tnow)
-            if tel:
-                self.tel.on_tokens(req.req_id, int(len(ts)), tnow)
-                if meta is not None:
-                    tok_ctx += len(ts) * float(meta[1][slot])
-            if spec is not None:
-                # draft-pool coverage after the round: the draft absorbed
-                # its proposals' KV up to the accepted/emitted frontier
-                # (req.total_len is still the dispatch-time context here —
-                # ``generated`` advances below)
-                nprop = int(spec[1][slot])
-                self._dlen[req.req_id] = req.total_len - 1 \
-                    + min(len(ts), nprop)
-                self.spec_rounds += 1
-                self.spec_proposed += nprop
-                self.spec_accepted += int(acc[slot])
+        # the fold of the horizon's emissions into host bookkeeping: a
+        # phase of its own, so a device gap that spans it is named
+        with self._phase("decode_s", "fold"):
+            self.timing.device_syncs += 1
+            tel = self.tel.enabled
+            # one readback timestamp for the whole horizon: every emission
+            # in it became host-visible at the same sync, and the
+            # per-request records must reproduce the first_tok_t-based TTFT
+            # exactly
+            tnow = self.clock()
+            tok_ctx = 0.0
+            finished = np.zeros((self.ecfg.n_slots,), bool)
+            for slot, req in pairs:
+                ts = toks[emit[:, slot], slot]
+                if not len(ts):            # pool-starved to zero steps
+                    continue
+                # invalid-logits quarantine: an injected NaN plan, or sampled
+                # ids outside the logits width (the fused path cannot see the
+                # device-side logits, so garbage shows up as out-of-range ids).
+                # The horizon's tokens are NOT folded — the request goes
+                # terminal at this tick's safe point with reason "nan"
+                if (self.faults.enabled
+                        and self.faults.fire("nan_logits", key=req.req_id)) \
+                        or (self.nan_guard
+                            and (int(ts.min()) < 0
+                                 or int(ts.max()) >= self.cfg.padded_vocab)):
+                    self._abort_req.setdefault(req.req_id, "nan")
+                    continue
+                self.outputs[req.req_id].extend(int(t) for t in ts)
+                self.first_tok_t.setdefault(req.req_id, tnow)
                 if tel:
-                    self.tel.on_spec(req.req_id, nprop, int(acc[slot]))
-            # the tick's step() already reserved one token; the rest of the
-            # horizon's emissions land here
-            req.generated += len(ts) - 1
-            self.tokens[slot] = int(ts[-1])
-            finished[slot] = bool(fin[slot])
-            if fin[slot] and self._dstate is not None:
-                self._dlen.pop(req.req_id, None)
-            self.timing.decode_tokens += int(len(ts))
-        if tel:
-            self.tel.on_horizon(tok_ctx)
+                    self.tel.on_tokens(req.req_id, int(len(ts)), tnow)
+                    if hctx is not None:
+                        tok_ctx += len(ts) * float(hctx[slot])
+                if spec is not None:
+                    # draft-pool coverage after the round: the draft
+                    # absorbed its proposals' KV up to the accepted/emitted
+                    # frontier (req.total_len is still the dispatch-time
+                    # context here — ``generated`` advances below)
+                    nprop = int(spec[1][slot])
+                    self._dlen[req.req_id] = req.total_len - 1 \
+                        + min(len(ts), nprop)
+                    self.spec_rounds += 1
+                    self.spec_proposed += nprop
+                    self.spec_accepted += int(acc[slot])
+                    if tel:
+                        self.tel.on_spec(req.req_id, nprop, int(acc[slot]))
+                # the tick's step() already reserved one token; the rest of the
+                # horizon's emissions land here
+                req.generated += len(ts) - 1
+                self.tokens[slot] = int(ts[-1])
+                finished[slot] = bool(fin[slot])
+                if fin[slot] and self._dstate is not None:
+                    self._dlen.pop(req.req_id, None)
+                self.timing.decode_tokens += int(len(ts))
+            if tel:
+                self.tel.on_horizon(tok_ctx)
         return finished
 
     def _step_fused(self) -> None:
@@ -1290,7 +1334,7 @@ class DecodeEngine:
         """
         E = self.ecfg
         # ---- overlap window: result-independent host work --------------
-        with self._phase("host_s", "host", "overlap"):
+        with self._phase("host_s", "overlap"):
             if self.cache is not None:
                 # an injected swap-tier stall skips the maintenance drain
                 # for the tick — pending swap-outs queue up, exactly the
@@ -1308,11 +1352,11 @@ class DecodeEngine:
             finished, self._pending_fin = self._pending_fin, None
 
         # ---- safe point: injection, deadlines, aborts, degradation -----
-        with self._phase("host_s", "host", "faults"):
+        with self._phase("host_s", "faults"):
             self._process_faults(finished)
 
         # ---- schedule + prefill ----------------------------------------
-        with self._phase("host_s", "host", "schedule"):
+        with self._phase("host_s", "schedule"):
             admitted, active = self.batcher.step(finished)
             if self.cache is not None and self.cache.has_pending:
                 # swap-in scatters / CoW copies queued by this tick's
@@ -1321,14 +1365,14 @@ class DecodeEngine:
                 self.state["pool"] = self.cache.apply_pending(
                     self.state["pool"])
         if admitted or self.prefiller.busy:
-            with self._phase("prefill_s", "prefill", "prefill"):
+            with self._phase("prefill_s", "prefill"):
                 active = self.prefiller.run(admitted, active)
         self.timing.steps += 1
         if not active:
             return
 
         # ---- horizon reservation + incremental config update -----------
-        with self._phase("host_s", "host", "config"):
+        with self._phase("host_s", "config"):
             # degradation bit 2 demotes speculative decode to the plain
             # fused scan (draft state parks; _dlen goes stale but is only
             # consulted behind ``spec``)
@@ -1357,24 +1401,25 @@ class DecodeEngine:
                 width = decode_table_bucket(self.batcher.max_live_pages(), W)
 
         # ---- dispatch; do NOT block ------------------------------------
-        with self._phase("decode_s", "dispatch",
-                         "spec_dispatch" if spec else "dispatch"):
+        with self._phase("decode_s", "spec_dispatch" if spec else "dispatch"):
             if self.tel.enabled:
-                self._horizon_seq += 1
-                self._horizon_meta = (time.perf_counter(),
-                                      self.batcher._ctx.copy(),
-                                      self._horizon_seq)
+                self._horizon_ctx = self.batcher._ctx.copy()
             if spec:
                 self._draft_catchup(active)
                 self._dispatch_spec(active, allow, int(K), int(width))
             else:
                 if self._fused_jit is None:
                     self._fused_jit = self._make_fused()
-                toks, emit, fin, self.state, self.dev.tokens, self.dev.ctx, \
-                    self.dev.rem, self.dev.key = self._fused_jit(
-                        self.params, self.state, self.dev.tokens, self.dev.bt,
-                        self.dev.ctx, self.dev.rem, jnp.asarray(allow),
-                        self.dev.key, horizon=int(K), width=int(width))
+                with self.timing.compiling(self._fused_jit):
+                    toks, emit, fin, self.state, self.dev.tokens, \
+                        self.dev.ctx, self.dev.rem, self.dev.key = \
+                        self._fused_jit(
+                            self.params, self.state, self.dev.tokens,
+                            self.dev.bt, self.dev.ctx, self.dev.rem,
+                            jnp.asarray(allow), self.dev.key,
+                            horizon=int(K), width=int(width))
+                # counted once the horizon is on its way to the device
+                self._count_decode_work(allow, int(K), int(width))
                 self._inflight = (toks, emit, fin,
                                   [(s, self.batcher.slots[s])
                                    for s in active],

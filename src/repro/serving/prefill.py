@@ -56,21 +56,22 @@ from repro.models import model as MDL
 def _make_batched_fn(cfg, rt):
     encdec = cfg.family == "encdec"
 
-    def fn(params, state, tokens, bt, last_idx, valid_len):
+    def prefill_batch(params, state, tokens, bt, last_idx, valid_len):
         frames = (jnp.zeros((tokens.shape[0], cfg.enc_seq, cfg.d_model),
                             cfg.dtype) if encdec else None)
         return MDL.prefill(cfg, params, state, tokens, bt,
                            last_idx=last_idx, valid_len=valid_len,
                            frames=frames, rt=rt)
-    return jax.jit(fn)
+    return jax.jit(prefill_batch)
 
 
 def _make_chunk_fn(cfg, rt):
-    def fn(params, state, tokens, bt, ctx_start, last_idx, valid_len):
+    def prefill_chunk(params, state, tokens, bt, ctx_start, last_idx,
+                      valid_len):
         return MDL.prefill_chunk(cfg, params, state, tokens, bt, ctx_start,
                                  last_idx=last_idx, valid_len=valid_len,
                                  rt=rt)
-    return jax.jit(fn)
+    return jax.jit(prefill_chunk)
 
 
 def _suffix_bucket(n: int, cap: int) -> int:
@@ -118,10 +119,11 @@ def prefill_suffix(eng, fn, grp) -> None:
     slots = [slot for slot, *_ in grp]
     bts = _group_tables(eng, slots,
                         max(len(seq) for _, _, seq, _, _ in grp))
-    logits, gstate = fn(
-        eng.params, eng._group_prefill_state(slots), jnp.asarray(toks),
-        jnp.asarray(bts), jnp.asarray(starts), jnp.asarray(lens - 1),
-        jnp.asarray(lens))
+    with eng.timing.compiling(fn):
+        logits, gstate = fn(
+            eng.params, eng._group_prefill_state(slots), jnp.asarray(toks),
+            jnp.asarray(bts), jnp.asarray(starts), jnp.asarray(lens - 1),
+            jnp.asarray(lens))
     eng._merge_group_state(slots, gstate)
     emits = [emit for _, _, _, emit, _ in grp]
     first = eng._first_tokens(logits, emits)     # one batched sample call
@@ -246,10 +248,11 @@ class BatchedPrefiller:
             slots = [slot for slot, _, _ in grp]
             bts = np.stack([eng.batcher.block_table_row(slot)
                             for slot in slots])
-            logits, gstate = self._fn(
-                eng.params, eng._group_prefill_state(slots),
-                jnp.asarray(toks), jnp.asarray(bts), jnp.asarray(lens - 1),
-                jnp.asarray(lens))
+            with eng.timing.compiling(self._fn):
+                logits, gstate = self._fn(
+                    eng.params, eng._group_prefill_state(slots),
+                    jnp.asarray(toks), jnp.asarray(bts),
+                    jnp.asarray(lens - 1), jnp.asarray(lens))
             eng._merge_group_state(slots, gstate)
             first = eng._first_tokens(logits, [fresh[s] for s in slots])
             for i, (slot, req, _) in enumerate(grp):
@@ -337,10 +340,11 @@ class ChunkedPrefiller:
             # attention reads nothing past the processed context, so the
             # table slice tracks the deepest cursor, not the full prompts
             bts = _group_tables(eng, slots, int((starts + lens).max()))
-            logits, gstate = self._fn(
-                eng.params, eng._group_prefill_state(slots),
-                jnp.asarray(toks), jnp.asarray(bts), jnp.asarray(starts),
-                jnp.asarray(lens - 1), jnp.asarray(lens))
+            with eng.timing.compiling(self._fn):
+                logits, gstate = self._fn(
+                    eng.params, eng._group_prefill_state(slots),
+                    jnp.asarray(toks), jnp.asarray(bts), jnp.asarray(starts),
+                    jnp.asarray(lens - 1), jnp.asarray(lens))
             eng._merge_group_state(slots, gstate)
             fin = [(i, slot, req, emit)
                    for i, (slot, req, prompt, emit, valid) in enumerate(grp)

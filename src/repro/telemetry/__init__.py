@@ -1,6 +1,6 @@
 """Unified telemetry for the serving stack.
 
-One facade object (``Telemetry``) wires the four pieces together and is
+One facade object (``Telemetry``) wires the three pieces together and is
 threaded through the engine as ``EngineConfig.telemetry``:
 
 * ``registry``  — metrics registry (``telemetry.registry``): push
@@ -9,44 +9,48 @@ threaded through the engine as ``EngineConfig.telemetry``:
 * ``tracker``   — per-request lifecycle records (``telemetry.tracing``):
   TTFT / TPOT / queue time / preemptions / spec accepts, percentile
   summaries, optional JSONL export;
-* ``trace``     — Chrome-trace/Perfetto tick timeline
-  (``telemetry.chrome_trace``): host / prefill / dispatch / sync tracks
-  plus the inferred device span, so DCS overlap is visible per tick;
 * ``pim``       — live PIM counters (``telemetry.pim_counters``): modeled
   HBM bytes/token, DPA occupancy/waste, pow2-bucket high-water, channel
   utilization.
 
 Disabled telemetry is the shared ``NULL`` singleton: ``enabled`` is False,
 every event method is a bound no-op, the scheduler's ``events`` hook stays
-unset and no binding, span or counter exists — the engine's behavior and
+unset and no binding or counter exists — the engine's behavior and
 device-sync count are bit-identical to a build without telemetry (tested).
+
+The timeline is the profiler's, not this package's: the engine's phases
+are ``jax.profiler`` spans (``engine.<phase>``) on the device trace's
+clock, and ``launch/serve.py --trace-out DIR`` captures them with the
+device ops (see docs/observability.md).
 """
 from __future__ import annotations
 
 from dataclasses import dataclass
 
-from repro.telemetry.chrome_trace import TraceWriter, validate_trace
 from repro.telemetry.pim_counters import PIMCounters
 from repro.telemetry.registry import (LATENCY_BUCKETS, MetricsRegistry,
                                       NullRegistry, parse_exposition)
 from repro.telemetry.tracing import RequestRecord, RequestTracker, percentile
 
 __all__ = [
-    "TelemetryConfig", "Telemetry", "make_telemetry", "NULL",
+    "TelemetryConfig", "Telemetry", "make_telemetry", "NULL", "PROGRAMS",
     "MetricsRegistry", "NullRegistry", "parse_exposition", "LATENCY_BUCKETS",
-    "TraceWriter", "validate_trace", "RequestTracker", "RequestRecord",
+    "RequestTracker", "RequestRecord",
     "PIMCounters", "percentile",
 ]
+
+
+# the engine's jitted programs, by the name their compiles are counted under
+# (``EngineTiming.compiles``) and their device ops carry (``jit_<name>``)
+PROGRAMS = ("decode_horizon", "decode_step", "prefill_batch", "prefill_chunk",
+            "patch_slots", "spec_propose", "spec_verify")
 
 
 @dataclass
 class TelemetryConfig:
     metrics: bool = True              # registry + bindings + PIM counters
-    trace: bool = False               # Perfetto tick timeline
-    trace_path: str | None = None     # implies trace when set
     request_log: str | None = None    # JSONL per-request record export
     namespace: str = "repro"
-    trace_max_events: int = 200_000
     pim_bytes_per_el: int = 2         # KV element width the PIM model uses
 
 
@@ -60,10 +64,7 @@ class Telemetry:
         self.cfg = cfg
         self.registry = (MetricsRegistry(cfg.namespace) if cfg.metrics
                          else NullRegistry(cfg.namespace))
-        self.trace = (TraceWriter(cfg.trace_max_events)
-                      if (cfg.trace or cfg.trace_path) else None)
-        self.tracker = RequestTracker(self.registry, self.trace,
-                                      cfg.request_log)
+        self.tracker = RequestTracker(self.registry, cfg.request_log)
         self.pim: PIMCounters | None = None
         self._kv_bpt = 0.0
 
@@ -93,6 +94,20 @@ class Telemetry:
                "prefill wall time", kind="counter")
         r.bind("engine_decode_seconds_total", lambda: t.decode_s,
                "decode dispatch + sync wall time", kind="counter")
+        r.bind("engine_decode_steps_total", lambda: t.decode_steps,
+               "decode steps dispatched", kind="counter")
+        for prog in PROGRAMS:
+            r.bind("engine_compiles_total",
+                   lambda p=prog: t.compiles.get(p, 0),
+                   "programs compiled (or loaded from the compile cache) "
+                   "at the engine's call sites", kind="counter",
+                   labels={"program": prog})
+        r.bind("paged_grid_cells_total", lambda: t.paged_grid_cells,
+               "paged-attention grid cells dispatched (slots x table "
+               "width per decode step)", kind="counter")
+        r.bind("paged_live_cells_total", lambda: t.paged_live_cells,
+               "grid cells holding a running slot's live KV page",
+               kind="counter")
         b = engine.batcher
         s = b.stats
         r.bind("sched_admitted_total", lambda: s.admitted,
@@ -209,21 +224,15 @@ class Telemetry:
                 pass
         return "telemetry: " + " ".join(parts)
 
-    def save_trace(self, path: str | None = None) -> int | None:
-        if self.trace is None:
-            return None
-        return self.trace.save(path or self.cfg.trace_path)
-
     def close(self) -> None:
         self.tracker.close()
 
 
 class _NullTelemetry:
     """Shared disabled singleton: same surface, every method a no-op, no
-    registry entries, no scheduler events hook, no trace."""
+    registry entries, no scheduler events hook."""
 
     enabled = False
-    trace = None
     tracker = None
     pim = None
 
@@ -256,9 +265,6 @@ class _NullTelemetry:
     def stats_line(self) -> str:
         return "telemetry: disabled"
 
-    def save_trace(self, path=None):
-        return None
-
     def close(self) -> None:
         pass
 
@@ -275,8 +281,7 @@ def make_telemetry(cfg) -> "Telemetry | _NullTelemetry":
     if isinstance(cfg, (Telemetry, _NullTelemetry)):
         return cfg
     if isinstance(cfg, TelemetryConfig):
-        if not (cfg.metrics or cfg.trace or cfg.trace_path
-                or cfg.request_log):
+        if not (cfg.metrics or cfg.request_log):
             return NULL
         return Telemetry(cfg)
     if cfg is True:

@@ -114,11 +114,10 @@ def percentile(vals: list[float], q: float) -> float:
 class RequestTracker:
     """Folds engine/scheduler events into per-request records."""
 
-    def __init__(self, registry=None, trace=None, log_path: str | None = None,
+    def __init__(self, registry=None, log_path: str | None = None,
                  clock=None):
         self.live: dict[int, RequestRecord] = {}
         self.records: list[RequestRecord] = []
-        self.trace = trace
         # injectable time source (the telemetry facade rebinds this to the
         # engine's clock at attach, so record timestamps live in the same
         # frame as the engine's deadlines — virtual or wall)
@@ -208,8 +207,6 @@ class RequestTracker:
         t = self.clock()
         rec.preemptions += 1
         rec.preempt_ts.append(t)
-        if self.trace is not None:
-            self.trace.instant(req.req_id, "preempt", t)
 
     def on_abort(self, req, slot: int, reason: str = "abort") -> None:
         """Terminal teardown without a natural finish (scheduler
@@ -226,8 +223,6 @@ class RequestTracker:
         rec.finish_t = t
         self.records.append(rec)
         self.c_aborted.inc()
-        if self.trace is not None:
-            self.trace.instant(req.req_id, f"abort:{reason}", t)
         if self._log is not None:
             self._log.write(json.dumps(rec.as_dict()) + "\n")
             self._log.flush()
@@ -248,18 +243,6 @@ class RequestTracker:
             self.h_tpot.observe(rec.tpot_s)
         if rec.queue_s is not None:
             self.h_queue.observe(rec.queue_s)
-        if self.trace is not None and rec.admit_t is not None:
-            self.trace.request_span(rec.req_id, "queue", rec.submit_t,
-                                    rec.admit_t)
-            if rec.first_token_t is not None:
-                self.trace.request_span(
-                    rec.req_id, "prefill", rec.admit_t, rec.first_token_t,
-                    args={"prompt_len": rec.prompt_len,
-                          "cached_tokens": rec.cached_tokens})
-                self.trace.request_span(
-                    rec.req_id, "decode", rec.first_token_t, rec.finish_t,
-                    args={"tokens": rec.tokens,
-                          "preemptions": rec.preemptions})
         if self._log is not None:
             self._log.write(json.dumps(rec.as_dict()) + "\n")
             self._log.flush()

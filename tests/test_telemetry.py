@@ -1,8 +1,11 @@
 """Telemetry correctness: deterministic-counter exactness against the
 subsystems' own ground truth across prefill modes / preemption / spec
-decoding, per-request records reproducing the engine's TTFT, Prometheus
-exposition + Perfetto trace round-trips, and the no-op-sink identity (a
-telemetry-disabled engine is token- and sync-count-identical)."""
+decoding, per-request records reproducing the engine's TTFT, the Prometheus
+exposition round-trip, the profiler's view of the engine (phase spans,
+named programs, program scopes), the decode-work and compile counters
+against hand counts, and the no-op-sink identity (a telemetry-disabled
+engine is token- and sync-count-identical)."""
+import re
 from dataclasses import replace
 
 import jax
@@ -13,9 +16,9 @@ import pytest
 from repro.configs import get_config, reduced
 from repro.models import model as MDL
 from repro.serving import DecodeEngine, EngineConfig
-from repro.telemetry import (NULL, TelemetryConfig, make_telemetry,
-                             parse_exposition, percentile, validate_trace)
-from repro.telemetry.chrome_trace import ENGINE_PID, TRACKS
+from repro.telemetry import (NULL, PROGRAMS, TelemetryConfig,
+                             make_telemetry, parse_exposition, percentile)
+from repro.telemetry.profile import read_profile
 from repro.serving import Request as Req
 
 PAGE = 4
@@ -33,11 +36,9 @@ def _setup():
 
 
 def _run(K=4, mode="batched", *, telemetry="on", n_pages=96, cache=False,
-         host_pages=0, budgets=BUDGETS, nreq=6, spec=False, spec_horizon=3,
-         trace=True):
+         host_pages=0, budgets=BUDGETS, nreq=6, spec=False, spec_horizon=3):
     cfg, params = _setup()
-    tel = (TelemetryConfig(metrics=True, trace=trace)
-           if telemetry == "on" else None)
+    tel = TelemetryConfig(metrics=True) if telemetry == "on" else None
     ecfg = EngineConfig(n_slots=3, page_size=PAGE, n_pages=n_pages,
                         max_context=64, eos_token=-1, prefill_mode=mode,
                         prefill_chunk=5,
@@ -215,24 +216,136 @@ def test_parse_exposition_rejects_malformed():
         parse_exposition("# TYPE h histogram\nh_sum 1\nh_count 1\n")
 
 
-def test_trace_has_pipeline_tracks():
-    """The fused tick writes host / dispatch / sync slices on distinct
-    engine tracks plus the inferred device span overlapping them (the DCS
-    picture), and per-request spans under the request pid."""
-    outs, eng = _run(8)
-    doc = eng.tel.trace.to_doc()
-    info = validate_trace(doc)
-    assert info["events"] > 0 and info["slices"] > 0
-    for track in ("host", "dispatch", "sync", "device"):
-        assert (ENGINE_PID, TRACKS[track]) in info["tracks"], track
-    # device spans (ph b/e) overlap the horizon: at least one per sync-ish
-    dev = [e for e in doc["traceEvents"]
-           if e.get("ph") == "b" and e.get("tid") == TRACKS["device"]]
-    assert dev
-    # request-lifecycle slices exist for finished requests (requests pid)
-    req = {e["name"] for e in doc["traceEvents"]
-           if e.get("pid") != ENGINE_PID and e.get("ph") == "X"}
-    assert {"queue", "prefill", "decode"} <= req
+def test_work_counters_in_exposition():
+    """The decode-step, compile and paged-grid counters are bound for
+    scraping and equal EngineTiming's, a compile sample per program."""
+    outs, eng = _run(4)
+    samples = parse_exposition(eng.tel.registry.render())
+    t = eng.timing
+    assert samples["repro_engine_decode_steps_total"] == t.decode_steps > 0
+    assert samples["repro_paged_grid_cells_total"] == t.paged_grid_cells
+    assert samples["repro_paged_live_cells_total"] == t.paged_live_cells
+    for prog in PROGRAMS:
+        key = f'repro_engine_compiles_total{{program="{prog}"}}'
+        assert samples[key] == t.compiles.get(prog, 0), prog
+    assert t.compiles["decode_horizon"] >= 1
+
+
+# ---------------------------------------------------------------------------
+# the profiler's view: engine phase spans, named programs, program scopes
+# ---------------------------------------------------------------------------
+
+def test_profile_has_engine_phases_and_named_programs(tmp_path):
+    """Under jax.profiler the fused tick's phases show as ``engine.*``
+    spans on the host, and the fused program's ops carry its name."""
+    _run(4)                              # compile outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        outs, eng = _run(4)
+    finally:
+        jax.profiler.stop_trace()
+    spans, ops = read_profile(str(tmp_path))
+    names = {n for n, _, _ in spans}
+    for phase in ("overlap", "collect", "fold", "faults", "schedule",
+                  "prefill", "config", "dispatch"):
+        assert f"engine.{phase}" in names, phase
+    assert all(e >= s for _, s, e in spans)
+    modules = {m for _, m, _, _ in ops}
+    assert "jit_decode_horizon" in modules, sorted(modules)
+    assert "jit_prefill_batch" in modules, sorted(modules)
+
+
+def test_step_path_phases_are_spans(tmp_path):
+    """The per-token ``step()`` path's phases are spans too, and its
+    program is ``jit_decode_step``."""
+    cfg, params = _setup()
+    ecfg = EngineConfig(n_slots=2, page_size=PAGE, n_pages=32,
+                        max_context=48, eos_token=-1)
+    eng = DecodeEngine(cfg, ecfg, params)
+    eng.submit(Req(0, np.arange(1, 6), 3))
+    eng.step()                           # prefill + first decode compile
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for _ in range(3):
+            eng.step()
+    finally:
+        jax.profiler.stop_trace()
+    spans, ops = read_profile(str(tmp_path))
+    names = {n for n, _, _ in spans}
+    for phase in ("schedule", "config", "decode_sample", "bookkeep"):
+        assert f"engine.{phase}" in names, phase
+    assert "jit_decode_step" in {m for _, m, _, _ in ops}
+    t = eng.timing
+    assert t.compiles["decode_step"] == 1
+    # three decode steps (budget 3) at contexts 6, 7, 8: two pages each
+    assert t.decode_steps == t.device_syncs == t.decode_tokens == 3
+    assert t.paged_live_cells == 6
+    assert t.paged_grid_cells == 3 * 2 * eng.pool_spec.max_pages_per_req
+
+
+def test_decode_program_scopes():
+    """The compiled fused program tags its compute with the model's
+    scopes: every one of ``DECODE_SCOPES`` and the layer scan ``layers``
+    appear in its ops' ``op_name`` metadata, under the program's name."""
+    outs, eng = _run(4)
+    d = eng.dev
+    allow = jnp.zeros((eng.ecfg.n_slots,), jnp.int32)
+    text = eng._fused_jit.lower(
+        eng.params, eng.state, d.tokens, d.bt, d.ctx, d.rem, allow, d.key,
+        horizon=4, width=d.bt.shape[1]).compile().as_text()
+    paths = [p for p in re.findall(r'op_name="([^"]+)"', text)
+             if p.startswith("jit(decode_horizon)/")]
+    parts = {c for p in paths for c in p.split("/")}
+    for scope in MDL.DECODE_SCOPES + ("layers",):
+        assert scope in parts, scope
+
+
+def test_decode_work_counters_hand_count():
+    """Decode steps, the paged grid and its live cells equal a hand count.
+    Every request runs its ``max_new`` decode steps at contexts p+1 ...
+    p+max_new (p its prompt), holding ceil(ctx / page) live pages at each;
+    each dispatch runs K scan steps over n_slots x W table slots (W = 13
+    here: 48 / 4 + 1 pages, too few to bucket)."""
+    cfg, params = _setup()
+    K, prompts, budgets = 4, [10, 5, 17], [9, 3, 6]
+    ecfg = EngineConfig(n_slots=3, page_size=PAGE, n_pages=64,
+                        max_context=48, eos_token=-1, decode_horizon=K)
+    eng = DecodeEngine(cfg, ecfg, params)
+    rng = np.random.default_rng(5)
+    for r, (p, n) in enumerate(zip(prompts, budgets)):
+        eng.submit(Req(r, rng.integers(0, cfg.vocab_size, size=p), n))
+    outs = eng.run(100)
+    assert [len(outs[r]) for r in range(3)] == [n + 1 for n in budgets]
+    t = eng.timing
+    W = eng.pool_spec.max_pages_per_req
+    assert W == 13
+    live = sum(-(-c // PAGE) for p, n in zip(prompts, budgets)
+               for c in range(p + 1, p + n + 1))
+    assert t.device_syncs == 3           # ceil(9 / 4) horizons, all at once
+    assert t.decode_steps == K * t.device_syncs == 12
+    assert t.paged_grid_cells == 12 * 3 * W
+    assert t.paged_live_cells == live == 76
+    assert 0 < t.paged_live_cells / t.paged_grid_cells <= 1
+
+
+def test_compiles_count_new_widths_once():
+    """``compiles`` rises once per new decode table width and not when a
+    width comes round again: one request grows from the 16-page bucket
+    into the 32-page one, a second repeats both widths."""
+    cfg, params = _setup()
+    ecfg = EngineConfig(n_slots=2, page_size=PAGE, n_pages=128,
+                        max_context=256, eos_token=-1, decode_horizon=4)
+    eng = DecodeEngine(cfg, ecfg, params)
+    rng = np.random.default_rng(6)
+    eng.submit(Req(0, rng.integers(0, cfg.vocab_size, size=60), 12))
+    eng.run(100)
+    c = dict(eng.timing.compiles)
+    assert c["decode_horizon"] == 2, c   # widths 16 and 32
+    assert c["prefill_batch"] >= 1 and c["patch_slots"] >= 1
+    eng.submit(Req(1, rng.integers(0, cfg.vocab_size, size=60), 12))
+    eng.run(100)
+    assert eng.timing.compiles["decode_horizon"] == 2
+    assert eng.timing.compiles["prefill_batch"] == c["prefill_batch"]
 
 
 # ---------------------------------------------------------------------------
@@ -252,7 +365,6 @@ def test_disabled_telemetry_identity():
     assert e_off.tel is NULL and not e_off.tel.enabled
     assert e_off.batcher.events is None
     assert e_off.tel.registry.render() == "\n"        # renders empty
-    assert e_off.tel.save_trace() is None
     e_off.tel.close()                                  # no-ops don't raise
 
 

@@ -63,3 +63,24 @@ def test_paged_attention_compiles_for_v5e(one_chip, variant, kw, rows):
 
     compiled = jax.jit(f).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text(), variant
+
+
+def test_paged_attention_kernel_is_named(one_chip):
+    """The kernel's custom call carries its own name, so a device trace or
+    an HLO dump finds it by name and not as the one Mosaic call there."""
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = [sds((B, KVH, G, D), jnp.bfloat16),
+            sds((P, PAGE, KVH, D), jnp.bfloat16),
+            sds((P, PAGE, KVH, D), jnp.bfloat16),
+            sds((B, W), jnp.int32), sds((B,), jnp.int32)]
+
+    def f(q, k, v, bt, ctx):
+        return paged_attention_partials(q, k, v, bt, ctx, interpret=False)
+
+    text = jax.jit(f).lower(*args).compile().as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 1
+    assert "%paged_attention" in calls[0].split(" = ")[0]
+    assert "/paged_attention/pallas_call" in calls[0]
