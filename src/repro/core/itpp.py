@@ -61,7 +61,8 @@ def itpp_decode_attention_shard(q, k_new, v_new, pool_k, pool_v, block_table,
                                 max_pages_per_req: int,
                                 ring_width: int = 0,
                                 cond_window: int = 0,
-                                kernels: KernelConfig | None = None):
+                                kernels: KernelConfig | None = None,
+                                layer=None):
     """shard_map body (or single-device when spec.page_axes == ()).
 
     q [B,H,D]; k_new/v_new [B,KVH,D]; pool_{k,v} [P_loc, page, KVH, D];
@@ -82,17 +83,32 @@ def itpp_decode_attention_shard(q, k_new, v_new, pool_k, pool_v, block_table,
     incoming token's K/V scatter rides the same dispatch (the kernel reads
     the post-write pool). ``None`` (and off-TPU autodetect) keeps the
     gather-then-dense reference math below — identical semantics, tested.
-    Returns (out [B,H,D], pool_k, pool_v).
+
+    ``layer``: with a (traced) layer index the pools are the whole stacked
+    ``[L, P_loc, page, KVH, D]`` pair, addressed in place: they are viewed
+    as ``[L*P_loc, ...]`` (a leading-dims reshape, a bitcast), and the
+    token's write target and the table's live entries shift by
+    ``layer*P_loc``, so no layer's pool is sliced out or written back. The
+    drop sentinel then is ``L*P_loc``, past the last layer.
+    Returns (out [B,H,D], pool_k, pool_v), the pools in their given shape.
     """
     B, maxp = block_table.shape
-    P_loc, page = pool_k.shape[0], pool_k.shape[1]
+    pool_shape = pool_k.shape
+    if layer is None:
+        P_loc, base = pool_k.shape[0], 0
+    else:
+        P_loc, base = pool_k.shape[1], layer * pool_k.shape[1]
+        pool_k = pool_k.reshape((-1,) + pool_k.shape[2:])
+        pool_v = pool_v.reshape((-1,) + pool_v.shape[2:])
+    page = pool_k.shape[1]
     sharded = bool(spec.page_axes)
     my = _my_page_shard(spec, mesh_axis_sizes) if sharded else jnp.int32(0)
 
     # ---- 1. write the incoming token where owned --------------------------
     with jax.named_scope("kv_write"):
         owned_w = (new_page // P_loc) == my
-        loc_w = jnp.where(owned_w, new_page - my * P_loc, P_loc)  # OOB: drop
+        loc_w = jnp.where(owned_w, new_page - my * P_loc + base,
+                          pool_k.shape[0])                     # OOB: drop
         pool_k = pool_k.at[loc_w, new_off].set(
             k_new.astype(pool_k.dtype), mode="drop")
         pool_v = pool_v.at[loc_w, new_off].set(
@@ -108,7 +124,7 @@ def itpp_decode_attention_shard(q, k_new, v_new, pool_k, pool_v, block_table,
         from repro.kernels.ref import combine_partials
         H = q.shape[1]
         KVH = pool_k.shape[2]
-        bt_loc = jnp.where(owned, block_table - my * P_loc, -1)
+        bt_loc = jnp.where(owned, block_table - my * P_loc + base, -1)
 
         def kernel_partial(mp_width: int, window_only: bool):
             if window_only:
@@ -152,7 +168,7 @@ def itpp_decode_attention_shard(q, k_new, v_new, pool_k, pool_v, block_table,
                                 stable=True)
             sel = order[:, :mp_width]
             bt_loc = jnp.take_along_axis(block_table, sel, axis=1) \
-                - my * P_loc
+                - my * P_loc + base
             vp_loc = jnp.take_along_axis(vpage, sel, axis=1)
             ok_loc = jnp.take_along_axis(pri, sel, axis=1)       # [B,mp]
             bt_safe = jnp.where(ok_loc, bt_loc, 0)
@@ -189,7 +205,8 @@ def itpp_decode_attention_shard(q, k_new, v_new, pool_k, pool_v, block_table,
         out = merge_partials(o, l, m, axis=spec.merge_axes)
     else:
         out = o / jnp.maximum(l, 1e-30)[..., None]
-    return out.astype(q.dtype), pool_k, pool_v
+    return (out.astype(q.dtype), pool_k.reshape(pool_shape),
+            pool_v.reshape(pool_shape))
 
 
 def make_prefill_writer(mesh, spec: ItppSpec, *, seq_axis: str):
@@ -242,10 +259,12 @@ def make_itpp_attention(mesh, spec: ItppSpec, *, max_pages_per_req: int,
     """Build the jit-composable sharded attention op.
 
     Returns f(q, k_new, v_new, pool_k, pool_v, bt, ctx, new_page, new_off,
-    window) -> (out, pool_k, pool_v), wrapped in shard_map over the mesh (or
-    plain when mesh is None — single-device tests). ``window`` may be traced.
-    ``kernels`` picks the shard-local compute (see
-    ``itpp_decode_attention_shard``).
+    window, layer=None) -> (out, pool_k, pool_v), wrapped in shard_map over
+    the mesh (or plain when mesh is None — single-device tests). ``window``
+    and ``layer`` may be traced; with ``layer`` the pools are the stacked
+    ``[L, P, ...]`` pair (see ``itpp_decode_attention_shard``), whose
+    layer axis no mesh axis shards. ``kernels`` picks the shard-local
+    compute.
     """
     sizes = dict(mesh.shape) if mesh is not None else {}
     body = partial(itpp_decode_attention_shard, spec=spec,
@@ -257,12 +276,20 @@ def make_itpp_attention(mesh, spec: ItppSpec, *, max_pages_per_req: int,
 
     b = spec.batch_axis
     qspec = P(b, None, None)
-    kvspec = P(b, None, None)
     bspec = P(b, None)
     cspec = P(b)
-    pool_spec = P(spec.page_axes, None, None, None)
-    out_specs = (qspec, pool_spec, pool_spec)
-    in_specs = (qspec, kvspec, kvspec, pool_spec, pool_spec, bspec, cspec,
-                cspec, cspec, P())
-    return shard_map(body, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_vma=False)
+
+    def over_mesh(pool_spec, f, *extra):
+        in_specs = (qspec, qspec, qspec, pool_spec, pool_spec, bspec, cspec,
+                    cspec, cspec, P(), *extra)
+        return shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=(qspec, pool_spec, pool_spec),
+                         check_vma=False)
+
+    per_layer = over_mesh(P(spec.page_axes, None, None, None), body)
+    stacked = over_mesh(P(None, spec.page_axes, None, None, None),
+                        lambda *a: body(*a[:-1], layer=a[-1]), P())
+
+    def attend(*args, layer=None):
+        return per_layer(*args) if layer is None else stacked(*args, layer)
+    return attend

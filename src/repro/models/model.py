@@ -59,15 +59,23 @@ class Runtime:
             return self.moe(p, cfg, x)
         return MOE.moe_local(p, cfg, x)
 
-    def itpp_apply(self, q, k, v, pk, pv, bt, ctx, npage, noff, window):
+    def itpp_apply(self, q, k, v, pk, pv, bt, ctx, npage, noff, window,
+                   layer=None):
+        """Decode attention over the pool pair ``pk``/``pv``: one layer's
+        ``[P, page, KVH, D]``, or with ``layer`` the whole stacked
+        ``[L, P, ...]`` pool addressed in place."""
         if self.itpp is not None:
-            return self.itpp(q, k, v, pk, pv, bt, ctx, npage, noff, window)
-        spec = ItppSpec((), (), None, 1, 1, pk.shape[1])
+            # hooks serving only per-layer pools (pipeline stages) take
+            # no ``layer``
+            extra = {} if layer is None else {"layer": layer}
+            return self.itpp(q, k, v, pk, pv, bt, ctx, npage, noff, window,
+                             **extra)
+        spec = ItppSpec((), (), None, 1, 1, pk.shape[-3])
         return itpp_decode_attention_shard(
             q, k, v, pk, pv, bt, ctx, npage, noff, window, spec=spec,
             mesh_axis_sizes={}, max_pages_per_req=bt.shape[1],
             ring_width=self.ring_width, cond_window=self.cond_window,
-            kernels=self.kernels)
+            kernels=self.kernels, layer=layer)
 
 
 DEFAULT_RT = Runtime()
@@ -379,8 +387,9 @@ DECODE_SCOPES = ("embed", "attn", "kv_write", "paged_attn", "mlp", "head",
 
 
 def _attn_block_decode(p, cfg, x, cs, window, pool_k, pool_v, bt, ctx,
-                       npage, noff, rt: Runtime, cross_kv=None):
-    """x [B, D] one token. Returns (x, pool_k, pool_v). The named scopes
+                       npage, noff, rt: Runtime, cross_kv=None, layer=None):
+    """x [B, D] one token. Returns (x, pool_k, pool_v); with ``layer`` the
+    pools are the whole stack (``Runtime.itpp_apply``). The named scopes
     (``attn``, ``paged_attn`` with ``kv_write`` inside it, ``mlp``) put the
     block's compute under those names in the compiled program's metadata;
     see ``DECODE_SCOPES``."""
@@ -394,7 +403,7 @@ def _attn_block_decode(p, cfg, x, cs, window, pool_k, pool_v, bt, ctx,
     with jax.named_scope("paged_attn"):
         a, pool_k, pool_v = rt.itpp_apply(
             q[:, 0], k[:, 0], v[:, 0], pool_k, pool_v, bt, ctx, npage, noff,
-            window)
+            window, layer=layer)
     with jax.named_scope("attn"):
         x = x + L.dense(a.reshape(B, cfg.q_dim), p["attn"]["wo"])
         if cross_kv is not None:
@@ -540,27 +549,31 @@ def decode_step(cfg, params, state, tokens, bt, ctx, npage, noff, *,
         stack = params["dec"] if cfg.family == "encdec" else params["layers"]
         has_cross = cfg.family == "encdec"
 
-        # pool layers ride as scan xs/ys (per-layer slices stream through the
-        # loop) rather than a carry + dynamic-update-slice: the carry pattern
-        # made XLA copy the WHOLE pool twice per layer — 88% of decode HBM
-        # traffic for gemma3-27b (EXPERIMENTS.md §Perf H1).
-        def body(h, xs):
+        # the whole stacked pool rides the scan's carry and each layer
+        # addresses its own pages in it (``layer=``): the token scatters in
+        # place and the kernel reads the stack. Passed as xs/ys instead, each
+        # layer's pool was sliced out, written back into a new ys stack, and
+        # copied whole into the horizon's carry: 30-58% of the decode step's
+        # device time on a TPU v5e, where the token write needs a few KiB.
+        def body(carry, xs):
+            h, pk, pv = carry
             if has_cross:
-                lp, w, pkl, pvl, ck, cv = xs
+                lp, w, i, ck, cv = xs
                 cross = (ck, cv)
             else:
-                lp, w, pkl, pvl = xs
+                lp, w, i = xs
                 cross = None
-            h, pkl, pvl = _attn_block_decode(lp, cfg, h, cs, w, pkl, pvl,
-                                             bt, ctx, npage, noff, rt,
-                                             cross_kv=cross)
-            return h, (pkl, pvl)
+            h, pk, pv = _attn_block_decode(lp, cfg, h, cs, w, pk, pv, bt,
+                                           ctx, npage, noff, rt,
+                                           cross_kv=cross, layer=i)
+            return (h, pk, pv), None
 
-        xs = ((stack, windows, pool["k"], pool["v"],
-               state["cross_k"], state["cross_v"])
-              if has_cross else (stack, windows, pool["k"], pool["v"]))
+        index = jnp.arange(windows.shape[0], dtype=jnp.int32)
+        xs = ((stack, windows, index, state["cross_k"], state["cross_v"])
+              if has_cross else (stack, windows, index))
         with jax.named_scope("layers"):
-            x, (pk, pv) = jax.lax.scan(body, x, xs)
+            (x, pk, pv), _ = jax.lax.scan(body, (x, pool["k"], pool["v"]),
+                                          xs)
         state["pool"] = {"k": pk, "v": pv}
     elif "mlstm" in params:
         def body(carry, xs):

@@ -64,6 +64,52 @@ def test_itpp_sharded_matches_oracle():
 
 
 @pytest.mark.slow
+def test_itpp_sharded_stacked_pool_in_place():
+    """The sharded op over the whole stacked [L, P, ...] pool with a traced
+    ``layer``: layer 1's pages are read and written in place on each shard,
+    the idle slot's dropped write lands nowhere, and the other layers come
+    back bit-identical."""
+    run_devprog("""
+        from repro.core import paged_kv as PK, itpp as IT
+        from repro.core.allocator import PageAllocator
+        B, H, KVH, D, page, maxp, n_pages, L = 4, 8, 2, 16, 4, 8, 64, 3
+        alloc = PageAllocator(n_pages, 8, page, policy="striped")
+        ctx_prev = np.array([13, 7, 22, 0], np.int32)
+        bts = []
+        for b in range(B - 1):
+            alloc.admit(b, int(ctx_prev[b]) + 1)
+            bts.append(alloc.block_table(b, maxp))
+        bts.append(np.full(maxp, -1, np.int32))               # idle slot
+        bt = jnp.asarray(np.stack(bts))
+        shape = (L, n_pages, page, KVH, D)
+        pool_k = jax.random.normal(jax.random.PRNGKey(0), shape)
+        pool_v = jax.random.normal(jax.random.PRNGKey(1), shape)
+        q = jax.random.normal(jax.random.PRNGKey(2), (B, H, D))
+        k_new = jax.random.normal(jax.random.PRNGKey(3), (B, KVH, D))
+        v_new = jax.random.normal(jax.random.PRNGKey(4), (B, KVH, D))
+        ctx = jnp.asarray(ctx_prev + 1)
+        npage = jnp.asarray([bts[b][int(ctx_prev[b]) // page]
+                             for b in range(B - 1)] + [n_pages])
+        noff = jnp.asarray(ctx_prev % page)
+        pk1, pv1 = PK.write_token(pool_k[1], pool_v[1], k_new, v_new, npage,
+                                  noff)
+        ref = PK.paged_decode_attention_ref(q, pk1, pv1, bt, ctx)
+        mesh = make_mesh((8,), ("model",))
+        spec = IT.ItppSpec(("model",), ("model",), None, 8, 8, page)
+        f = IT.make_itpp_attention(mesh, spec, max_pages_per_req=maxp)
+        out, pk, pv = jax.jit(f)(q, k_new, v_new, pool_k, pool_v, bt, ctx,
+                                 npage, noff, 0, layer=jnp.int32(1))
+        assert pk.shape == pv.shape == shape
+        err = np.abs(np.asarray(out)[:B - 1] - np.asarray(ref)[:B - 1]).max()
+        assert err < 1e-5, err
+        for got, was, one in ((pk, pool_k, pk1), (pv, pool_v, pv1)):
+            assert (np.asarray(got[1]) == np.asarray(one)).all()
+            for l in (0, 2):
+                assert (np.asarray(got[l]) == np.asarray(was[l])).all()
+    """)
+
+
+@pytest.mark.slow
 def test_moe_ep_matches_local():
     run_devprog("""
         from dataclasses import replace

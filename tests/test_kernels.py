@@ -314,6 +314,87 @@ def test_itpp_kernel_traced_window_scan():
                                    atol=3e-5, rtol=3e-5)
 
 
+# ---------------------------------------------------------------------------
+# decode horizon: the layer scan addresses the stacked pool in place
+# ---------------------------------------------------------------------------
+
+def _hand_sliced(rt):
+    """``rt`` with every ``layer=`` call served the old way: that layer's
+    pool sliced out by hand, attended with ``layer=None``, written back."""
+    from dataclasses import replace
+
+    def itpp(q, k, v, pk, pv, bt, ctx, npage, noff, window, layer=None):
+        out, pkl, pvl = rt.itpp_apply(q, k, v, pk[layer], pv[layer], bt, ctx,
+                                      npage, noff, window)
+        return out, pk.at[layer].set(pkl), pv.at[layer].set(pvl)
+    return replace(rt, itpp=itpp)
+
+
+@pytest.mark.parametrize("kernel", ["gather", "pallas"])
+@pytest.mark.parametrize("mode", ["full", "cond_window", "ring"])
+def test_decode_multi_in_place_pool_matches_hand_sliced(mode, kernel):
+    """decode_multi over the stacked pool (each layer's pages addressed in
+    place) gives the tokens and final pools of the per-layer sliced path,
+    for full, windowed and ring pools. Slot 2 freezes after its first step
+    and slot 3 is idle, so their writes take the drop sentinel; no table
+    holds page 0, so every layer's page 0 must come back untouched (a
+    sentinel of ``P`` in the flat view would land on layer l+1's)."""
+    from dataclasses import replace
+    from repro.configs import get_config, reduced
+    from repro.models import model as MDL
+    from repro.serving.sampling import make_scan_sampler
+    pattern, ring = {"full": (("attn",), False),
+                     "cond_window": (("local", "attn"), False),
+                     "ring": (("local",), True)}[mode]
+    cfg = replace(reduced(get_config("llama3.2-1b"), layers=3),
+                  pattern=pattern, sliding_window=8, dtype="float32")
+    params = MDL.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
+    page, P, horizon = 4, 40, 3
+    W = 4 if ring else 8
+    ctx = np.asarray([23, 6, 18, 0] if ring else [20, 5, 9, 0], np.int32)
+    rem = jnp.asarray([10, 10, 1, 0], jnp.int32)
+    allow = jnp.asarray([horizon, horizon, horizon, 0], jnp.int32)
+    bt = np.full((4, W), -1, np.int32)
+    free = list(np.random.default_rng(0).permutation(np.arange(1, P)))
+    for b, c in enumerate(ctx):
+        if c:
+            n = min(-(-(int(c) + horizon) // page), W)
+            bt[b, :n] = [free.pop() for _ in range(n)]
+    shape = (cfg.n_layers, P, page, cfg.n_kv_heads, cfg.d_head)
+    pool = {"k": jax.random.normal(jax.random.PRNGKey(1), shape),
+            "v": jax.random.normal(jax.random.PRNGKey(2), shape)}
+    tokens = jnp.asarray([3, 5, 7, 11], jnp.int32)
+    kernels = (None if kernel == "gather" else
+               KernelConfig(use_pallas=True, interpret=True, n_splits=2))
+    rt = MDL.Runtime(kernels=kernels, ring_width=W if ring else 0,
+                     cond_window=8 if mode == "cond_window" else 0)
+
+    def run(rt):
+        return jax.jit(lambda st: MDL.decode_multi(
+            cfg, params, st, tokens, jnp.asarray(bt), jnp.asarray(ctx), rem,
+            allow, jax.random.PRNGKey(3), horizon=horizon, table_width=W,
+            page_size=page, n_pages=P, eos_token=-1,
+            sample=make_scan_sampler("greedy"), rt=rt))({"pool": pool})
+
+    toks, emit, fin, state, *_ = run(rt)
+    toks_h, emit_h, fin_h, state_h, *_ = run(_hand_sliced(rt))
+    np.testing.assert_array_equal(np.asarray(toks), np.asarray(toks_h))
+    np.testing.assert_array_equal(np.asarray(emit), np.asarray(emit_h))
+    np.testing.assert_array_equal(np.asarray(fin), np.asarray(fin_h))
+    assert np.asarray(emit)[:, 2].tolist() == [True, False, False]
+    assert not np.asarray(emit)[:, 3].any()
+    for half in ("k", "v"):
+        got = np.asarray(state["pool"][half])
+        np.testing.assert_allclose(got, np.asarray(state_h["pool"][half]),
+                                   atol=1e-5, rtol=1e-5)
+        np.testing.assert_array_equal(got[:, 0], np.asarray(pool[half])[:, 0])
+        # the running slots' tokens did land, in every layer
+        t = int(ctx[0]) - 1
+        vp = (t // page) % W if ring else t // page
+        assert (got[:, bt[0, vp], t % page]
+                != np.asarray(pool[half])[:, bt[0, vp], t % page]).all()
+
+
 def test_flash_decode_tail_split():
     """T that does not divide n_splits: the tail split is padded+masked."""
     B, KVH, G, D, T, S = 2, 2, 2, 8, 21, 4
