@@ -14,6 +14,7 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import re
 import sys
 from pathlib import Path
 
@@ -117,7 +118,10 @@ def main(argv=None) -> None:
                       horizon=ecfg.decode_horizon, width=W).compile()
         r = analyse(c)
         text = c.as_text()
-        r["tpu_custom_call"] = "tpu_custom_call" in text
+        ops = [line.strip().removeprefix("ROOT ")
+               for line in text.splitlines()]
+        r["kernels"] = {k: sum(1 for op in ops if re.search(p, op))
+                        for k, p in cell.arch_of(cf).KERNELS.items()}
         r["whole_pool_copies"] = sum(
             1 for line in text.splitlines()
             if " copy(" in line and

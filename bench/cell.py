@@ -6,7 +6,14 @@ or cell lives in a file of its own under ``bench/``, found by the name that
 ``BENCHMARK.json`` gives it:
 
 - ``bench/configs/<config>.json``: the published config keys, as run, with
-  the engine settings and what was assumed;
+  the engine settings, what was assumed, and ``bench_arch``, the name of
+  its architecture module;
+- ``bench/arch/<arch>.py``: everything that depends on the block: the
+  check that the program serves it (``check``), the model dims (``dims``),
+  the served weights (``make``), the float32 reference's hidden states
+  (``hidden``), the work of the algorithm (``decode_flops``,
+  ``prefill_flops``, ``kernel_work``, ``step_bytes``, ``session_pages``)
+  and the kernels' names in the device trace (``KERNELS``);
 - ``bench/traffic/<mix>.json``: a traffic mix's parameters, with the name
   of its kind;
 - ``bench/traffic/<kind>.py``: a traffic kind, with ``requests``, ``setup``
@@ -21,6 +28,7 @@ import importlib.util
 import json
 import sys
 from dataclasses import replace
+from functools import cache
 from pathlib import Path
 
 BENCH = Path(__file__).resolve().parent
@@ -87,21 +95,35 @@ def metrics_for(bm: dict, cell: str, kind: str) -> list[dict]:
             if "workloads" not in m or cell in m["workloads"]]
 
 
-# published config key -> the ModelConfig field it sets
-_WIDTHS = {
-    "hidden_size": "d_model",
-    "intermediate_size": "d_ff",
-    "num_attention_heads": "n_heads",
-    "num_key_value_heads": "n_kv_heads",
-    "vocab_size": "vocab_size",
-}
+@cache
+def _arch_at(path: Path, name: str):
+    return _module(path, f"bench_arch_{name}")
+
+
+def arch(name: str):
+    """The architecture module ``bench/arch/<name>.py``, loaded once."""
+    path = BENCH / "arch" / f"{name}.py"
+    if not path.is_file():
+        have = sorted(p.stem for p in (BENCH / "arch").glob("*.py"))
+        raise SystemExit(f"bench: no architecture module {name!r}; "
+                         f"bench/arch/ holds {have}")
+    return _arch_at(path, name)
+
+
+def arch_of(cf: dict):
+    """The architecture module that configuration file ``cf`` names."""
+    if "bench_arch" not in cf:
+        raise SystemExit(f"bench: {cf['name']}: the configuration file "
+                         f"names no bench_arch (a module under bench/arch/)")
+    return arch(cf["bench_arch"])
 
 
 def model_config(cf: dict):
     """The program's ModelConfig for configuration file ``cf``: the
     registered architecture at its published widths, cut to the file's
     depth, with the file's norm epsilon, rope base and dtype. Raises where
-    the program's widths differ from the file's."""
+    the file's architecture module finds that the program does not serve
+    its block at the file's widths."""
     use_program()
     from repro.launch import serve
     # ``program_full`` false takes the architecture's reduced float32
@@ -112,15 +134,7 @@ def model_config(cf: dict):
                   rope_theta=float(cf["rope_theta"]),
                   dtype=cf["torch_dtype"],
                   tie_embeddings=bool(cf["tie_word_embeddings"]))
-    for key, field in _WIDTHS.items():
-        if getattr(cfg, field) != cf[key]:
-            raise SystemExit(f"bench: {cf['name']}: program {field}="
-                             f"{getattr(cfg, field)} but {key}={cf[key]}")
-    if cfg.d_head * cfg.n_heads != cf["hidden_size"] or \
-            cfg.family != "dense" or cfg.act != "swiglu" or cfg.qk_norm:
-        raise SystemExit(f"bench: {cf['name']}: the program's "
-                         f"{cf['program_arch']} is not the published dense "
-                         f"SwiGLU block")
+    arch_of(cf).check(cf, cfg)
     return cfg
 
 

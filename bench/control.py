@@ -38,7 +38,6 @@ def main(argv=None) -> None:
     import faults
     import harness
     import reference
-    import weights
 
     c = harness.Cell.by_name(args.workload)
     if args.fault:
@@ -52,11 +51,11 @@ def main(argv=None) -> None:
             "metrics": {k: v["value"] for k, v in res["metrics"].items()}}
     if not args.fault:
         t = time.perf_counter()
-        m = rec["dims"]
-        params = weights.make(m, args.seed, c.config["torch_dtype"])
+        m, arch = rec["dims"], cell.arch(rec["arch"])
+        params = arch.make(m, args.seed, c.config["torch_dtype"])
         line["control"] = max(
             float(reference.served_gaps(
-                params, m, np.asarray(rec["prompts"][rid], np.int32),
+                arch, params, m, np.asarray(rec["prompts"][rid], np.int32),
                 np.asarray(served, np.int32), control=True).max())
             for rid, served in rec["served"].items())
         line["control_s"] = time.perf_counter() - t

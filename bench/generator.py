@@ -4,8 +4,10 @@ seed, and lengths from a mix's distribution.
 A traffic mix is a data file, ``bench/traffic/<mix>.json``, that names its
 kind (``"kind"``). The kind is a module of its own,
 ``bench/traffic/<kind>.py``, found by that name (``cell.traffic_kind``):
-it makes the mix's requests from the seed (``requests``), sets them up
-(``setup``) and drives them through the timed window (``drive``), through
+it makes the mix's requests from the seed (``requests(mix, arch, m,
+engine, run_seconds, seed)``, with the configuration's architecture module
+``arch`` and its dims ``m``), sets them up (``setup``) and drives them
+through the timed window (``drive``), through
 the harness's ``Run``, which stamps what happens on the host clock. So a
 new mix of a kind that exists is one data file, and a new kind is one
 module, with no edit to the harness.

@@ -3,14 +3,16 @@ and the result line.
 
 Set-up builds the served engine (``DecodeEngine``: batched prefill, the
 fused decode horizon, the compiled Pallas paged-attention kernel on a
-TPU) around weights made on the device from the seed. The cell's traffic
+TPU) around weights made on the device from the seed by the
+configuration's architecture module (``bench/arch/<name>.py``, which gives
+the dims, weights, reference, work and kernel names). The cell's traffic
 kind (``bench/traffic/<kind>.py``, named by its mix) makes the requests,
 sets them up and warms every program shape the window can reach, then
 drives the engine through the window, all through ``Run``, which stamps
 submissions, tokens and pages in use on the host clock. After the window
 the peak device memory is read, the engine's state is freed, and the
 served tokens of a sample of requests are compared with the float32
-reference (``bench/reference.py``).
+reference (``bench/reference.py`` over the module's ``hidden``).
 """
 from __future__ import annotations
 
@@ -28,11 +30,6 @@ import cell as cells
 import reference
 import trace as tracing
 import weights
-
-# kernel name -> pattern of its operations' names in the device trace
-# (the paged decode kernel is the decode step's one Mosaic custom call; its
-# pallas_call carries no name of its own)
-KERNELS = {"paged_attn": "tpu_custom_call"}
 
 
 @dataclass
@@ -263,17 +260,19 @@ def run(c: Cell, seed: int, seconds: float, trace: bool, t_start: float,
     cf = c.config
     cfg = cells.model_config(cf)
     ecfg = cells.engine_config(cf)
-    m = weights.dims(cf, cfg.vocab_multiple)
+    arch = cells.arch_of(cf)
+    m = arch.dims(cf, cfg.vocab_multiple)
     kind = cells.traffic_kind(c.traffic["kind"])
 
     # ---- set-up ---------------------------------------------------------
     marks = [("start", t_start), ("device", time.perf_counter())]
-    params = weights.make(m, seed, cf["torch_dtype"])
+    params = arch.make(m, seed, cf["torch_dtype"])
     check_layout(params, cfg)
     jax.block_until_ready(params)
     marks.append(("weights", time.perf_counter()))
     eng = DecodeEngine(cfg, ecfg, params=params)
-    reqs = kind.requests(c.traffic, m, cf["engine"], c.run_seconds, seed)
+    reqs = kind.requests(c.traffic, arch, m, cf["engine"], c.run_seconds,
+                         seed)
     h = Run(eng)
     marks.append(("engine", time.perf_counter()))
     kind.setup(h, reqs)
@@ -329,7 +328,8 @@ def run(c: Cell, seed: int, seconds: float, trace: bool, t_start: float,
         f"{tuple(b - a for a, b in zip(c0, c1))}")
 
     rec = {
-        "cell": c.name, "dims": m, "peaks": peak, "seconds": seconds,
+        "cell": c.name, "arch": cf["bench_arch"], "dims": m, "peaks": peak,
+        "seconds": seconds,
         "setup_s": h.t0 - t_start, "window_s": window_s, "tokens": tokens,
         "ticks": tm1[0] - tm0[0], "host_s": tm1[1] - tm0[1],
         "horizons": tm1[2] - tm0[2], "decode_tokens": tm1[3] - tm0[3],
@@ -340,7 +340,8 @@ def run(c: Cell, seed: int, seconds: float, trace: bool, t_start: float,
     }
     if trace:
         try:
-            rec["trace"] = tracing.reduce(*tracing.load(logdir), KERNELS)
+            rec["trace"] = tracing.reduce(*tracing.load(logdir),
+                                          arch.KERNELS)
         finally:
             shutil.rmtree(logdir, ignore_errors=True)
         dev["busy_s"] = rec["trace"]["busy_s"]
@@ -353,8 +354,8 @@ def run(c: Cell, seed: int, seconds: float, trace: bool, t_start: float,
     del eng, h
     gc.collect()
     t2 = time.perf_counter()
-    gaps = [reference.served_gaps(params, m, r.prompt, served[r.req_id])
-            for r in sample]
+    gaps = [reference.served_gaps(arch, params, m, r.prompt,
+                                  served[r.req_id]) for r in sample]
     worst = float(max((g.max() for g in gaps), default=math.inf))
     limit = float(c.limits["max_logit_gap"]["limit"])
     n_checked = int(sum(len(g) for g in gaps))

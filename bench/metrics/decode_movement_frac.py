@@ -1,8 +1,7 @@
 """Model step: share of the decode program's device time in ops under
-none of its compute scopes (``scopes.COMPUTE``: ``embed``, ``attn``,
-``kv_write``, ``paged_attn``, ``mlp``, ``head``, ``sample``): the layer
-scan's slices of the KV pool and of the stacked weights, the pool's
-write-back, and XLA's copies."""
+none of its compute scopes (``scopes.COMPUTE``, the program's
+``DECODE_SCOPES``): the layer scan's slices of the KV pool and of the
+stacked weights, the pool's write-back, and XLA's copies."""
 from scopes import COMPUTE
 
 

@@ -1,18 +1,19 @@
-"""Plain float32 reference of the dense SwiGLU decoder, and the same
-reference computed in float8 as the control that has to fail.
+"""Plain float32 reference, and the same reference computed in float8 as
+the control that has to fail: what every architecture shares.
 
-It follows the published block (pre-norm RMSNorm, rotary embedding by
-halves, grouped-query causal softmax attention, SwiGLU MLP, final norm,
-untied head) and reads the weights in the served layout that
-``bench/weights.py`` describes: norm gains are offsets from 1, the head's
-columns past the published vocabulary are padding and are left out. It
-imports nothing of the program. Every matmul runs at
-``Precision.HIGHEST``, since a float32 matmul on a TPU otherwise runs in
-bfloat16.
+An architecture module (``bench/arch/<name>.py``) gives ``hidden``, its
+block's final-norm hidden states over a whole sequence, built from the
+pieces here; ``served_gaps`` reads the logits from them. The reference
+imports nothing of the program and reads the weights in the served layout
+that the module describes: norm gains are offsets from 1, the head's
+columns past the published vocabulary are padding and are left out. Every
+matmul runs at ``Precision.HIGHEST``, since a float32 matmul on a TPU
+otherwise runs in bfloat16.
 
-It runs a whole sequence a layer at a time, with attention in blocks of
-query rows, so that a 30k-token sequence fits beside the weights, and
-makes logits only at the positions asked for, in blocks of rows.
+A module runs a whole sequence a layer at a time, with attention in blocks
+of ``Q_BLOCK`` query rows, so that a 30k-token sequence fits beside the
+weights; logits are made only at the positions asked for, in blocks of
+rows.
 
 The control (``fp8=True``) rounds every matmul input to float8 e4m3 with
 a scale per row of activations and per output column of weights: the
@@ -20,7 +21,6 @@ weights, the activations, q, k, v and the attention probabilities.
 """
 from __future__ import annotations
 
-import math
 from functools import partial
 
 import jax
@@ -42,7 +42,7 @@ def fp8_round(x, axis):
     return (x / s).astype(jnp.float8_e4m3fn).astype(jnp.float32) * s
 
 
-def _mm(x, w, fp8):
+def mm(x, w, fp8):
     """x [..., k] @ w [k, n] in float32 (float8 inputs for the control)."""
     w = w.astype(jnp.float32)
     if fp8:
@@ -50,12 +50,12 @@ def _mm(x, w, fp8):
     return jnp.dot(x, w, precision=HI)
 
 
-def _norm(x, g, eps):
+def norm(x, g, eps):
     var = jnp.mean(x * x, axis=-1, keepdims=True)
     return x * jax.lax.rsqrt(var + eps) * (1.0 + g.astype(jnp.float32))
 
 
-def _rope(x, cos, sin):
+def rope(x, cos, sin):
     """x [S, heads, dh]; rotation of the two halves of each head."""
     h = x.shape[-1] // 2
     x1, x2 = x[..., :h], x[..., h:]
@@ -70,68 +70,29 @@ def rope_tables(S: int, dh: int, theta: float):
             jnp.asarray(np.sin(ang), jnp.float32))
 
 
-@partial(jax.jit, static_argnames=("dims", "fp8"))
-def _layer(x, layers, i, cos, sin, *, dims, fp8):
-    H, KVH, dh, eps = dims
-    S = x.shape[0]
-    G = H // KVH
-    lp = jax.tree.map(lambda a: a[i], layers)
-    h = _norm(x, lp["ln1"], eps)
-    q = _rope(_mm(h, lp["attn"]["wq"], fp8).reshape(S, H, dh), cos, sin)
-    k = _rope(_mm(h, lp["attn"]["wk"], fp8).reshape(S, KVH, dh), cos, sin)
-    v = _mm(h, lp["attn"]["wv"], fp8).reshape(S, KVH, dh)
-    if fp8:
-        q, k, v = fp8_round(q, -1), fp8_round(k, -1), fp8_round(v, 0)
-    q = q.reshape(S // Q_BLOCK, Q_BLOCK, KVH, G, dh)
-    kpos = jnp.arange(S)
-
-    def block(args):
-        qb, b = args
-        s = jnp.einsum("qkgd,tkd->kgqt", qb, k,
-                       precision=HI) / math.sqrt(dh)
-        qpos = b * Q_BLOCK + jnp.arange(Q_BLOCK)
-        s = jnp.where(kpos[None, :] <= qpos[:, None], s, -jnp.inf)
-        p = jax.nn.softmax(s, axis=-1)
-        if fp8:
-            p = fp8_round(p, -1)
-        return jnp.einsum("kgqt,tkd->qkgd", p, v, precision=HI)
-
-    a = jax.lax.map(block, (q, jnp.arange(S // Q_BLOCK)))
-    x = x + _mm(a.reshape(S, H * dh), lp["attn"]["wo"], fp8)
-    h = _norm(x, lp["ln2"], eps)
-    gate = jax.nn.silu(_mm(h, lp["mlp"]["w1"], fp8))
-    up = _mm(h, lp["mlp"]["w3"], fp8)
-    return x + _mm(gate * up, lp["mlp"]["w2"], fp8)
+def padded_ids(seq: np.ndarray) -> np.ndarray:
+    """``seq`` padded with id 0 to a multiple of ``SEQ_MULTIPLE`` (and so of
+    ``Q_BLOCK``); the padding comes after every real position, so causal
+    attention keeps it out of their rows."""
+    S = -(-len(seq) // SEQ_MULTIPLE) * SEQ_MULTIPLE
+    ids = np.zeros((S,), np.int32)
+    ids[:len(seq)] = seq
+    return ids
 
 
 @jax.jit
-def _embed(embed, ids):
-    return jnp.take(embed, ids, axis=0).astype(jnp.float32)
+def embed(table, ids):
+    return jnp.take(table, ids, axis=0).astype(jnp.float32)
 
 
 @partial(jax.jit, static_argnames=("eps",))
-def _final(x, g, *, eps):
-    return _norm(x, g, eps)
+def final_norm(x, g, *, eps):
+    return norm(x, g, eps)
 
 
 @partial(jax.jit, static_argnames=("V", "fp8"))
 def _logits(h, head, *, V, fp8):
-    return _mm(h, head[:, :V], fp8)
-
-
-def hidden(params, m: dict, seq: np.ndarray, *, fp8: bool = False):
-    """Final-norm hidden states [len(seq), d] in float32."""
-    n = len(seq)
-    S = -(-n // SEQ_MULTIPLE) * SEQ_MULTIPLE
-    ids = np.zeros((S,), np.int32)
-    ids[:n] = seq
-    cos, sin = rope_tables(S, m["dh"], m["theta"])
-    dims = (m["H"], m["KVH"], m["dh"], m["eps"])
-    x = _embed(params["embed"], jnp.asarray(ids))
-    for i in range(m["L"]):
-        x = _layer(x, params["layers"], jnp.int32(i), cos, sin, dims=dims,
-                   fp8=fp8)
-    return _final(x, params["final_norm"], eps=m["eps"])[:n]
+    return mm(h, head[:, :V], fp8)
 
 
 @jax.jit
@@ -145,12 +106,13 @@ def _gaps(ref, chosen):
     return jnp.where(ok, ref.max(-1) - got, jnp.inf)
 
 
-def served_gaps(params, m: dict, prompt: np.ndarray, served: np.ndarray,
-                *, control: bool = False) -> np.ndarray:
+def served_gaps(arch, params, m: dict, prompt: np.ndarray,
+                served: np.ndarray, *, control: bool = False) -> np.ndarray:
     """For each served token j (the first one comes from the prefill), the
     gap by which the reference's logit of it at its position lies below
-    the reference's best there. With ``control``, the gap of the token the
-    float8 reference puts first at the same positions instead."""
+    the reference's best there, with the hidden states of architecture
+    module ``arch``. With ``control``, the gap of the token the float8
+    reference puts first at the same positions instead."""
     p, n = len(prompt), len(served)
     seq = np.concatenate([prompt, served[:-1]]).astype(np.int32)
     # positions padded to whole blocks of rows (the pad repeats the last
@@ -159,8 +121,8 @@ def served_gaps(params, m: dict, prompt: np.ndarray, served: np.ndarray,
     pos = jnp.asarray(np.minimum(pos, p + n - 2))
     tok = np.zeros((len(pos),), np.int32)
     tok[:n] = served
-    h = hidden(params, m, seq)[pos]
-    hc = hidden(params, m, seq, fp8=True)[pos] if control else None
+    h = arch.hidden(params, m, seq)[pos]
+    hc = arch.hidden(params, m, seq, fp8=True)[pos] if control else None
     out = []
     for r in range(0, len(pos), ROW_BLOCK):
         ref = _logits(h[r:r + ROW_BLOCK], params["head"], V=m["V"],

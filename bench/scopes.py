@@ -198,7 +198,6 @@ def profile_window(c, seed: int, seconds: float, t_start: float,
 
     import cell as cells
     import harness
-    import weights
     cells.use_program()
     from repro.launch import serve
     from repro.serving import DecodeEngine
@@ -209,11 +208,13 @@ def profile_window(c, seed: int, seconds: float, t_start: float,
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     cf = c.config
     cfg, ecfg = cells.model_config(cf), cells.engine_config(cf)
-    m = weights.dims(cf, cfg.vocab_multiple)
+    arch = cells.arch_of(cf)
+    m = arch.dims(cf, cfg.vocab_multiple)
     kind = cells.traffic_kind(c.traffic["kind"])
-    params = weights.make(m, seed, cf["torch_dtype"])
+    params = arch.make(m, seed, cf["torch_dtype"])
     eng = DecodeEngine(cfg, ecfg, params=params)
-    reqs = kind.requests(c.traffic, m, cf["engine"], c.run_seconds, seed)
+    reqs = kind.requests(c.traffic, arch, m, cf["engine"], c.run_seconds,
+                         seed)
     h = harness.Run(eng)
     kind.setup(h, reqs)
     t = eng.timing

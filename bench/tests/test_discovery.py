@@ -26,7 +26,7 @@ from dataclasses import replace
 import generator
 
 
-def requests(mix, m, engine, run_seconds, seed):
+def requests(mix, arch, m, engine, run_seconds, seed):
     rng = generator.token_rng(seed)
     return [generator.Request(i, generator.token_ids(rng, m["V"], n),
                               mix["new_tokens"], due_s=0.0)

@@ -8,9 +8,8 @@ import pytest
 
 import cell
 import generator
-import weights
-import work
 
+ARCH = cell.arch("dense_swiglu")
 TRAFFIC = cell.traffic_file("decode_long")
 CLOSED = cell.traffic_kind("closed")
 RUN_S = cell.benchmark()["run_seconds"]
@@ -18,8 +17,9 @@ RUN_S = cell.benchmark()["run_seconds"]
 
 def _plan(config, seed=0):
     cf = cell.config_file(config)
-    m = weights.dims(cf)
-    return CLOSED.requests(TRAFFIC, m, cf["engine"], RUN_S, seed), m, cf
+    m = ARCH.dims(cf)
+    return (CLOSED.requests(TRAFFIC, ARCH, m, cf["engine"], RUN_S, seed), m,
+            cf)
 
 
 @pytest.mark.parametrize("config,contexts", [
@@ -33,8 +33,8 @@ def test_closed_batch_sizes(config, contexts):
     assert TRAFFIC["kind"] == "closed"
     assert [len(r.prompt) for r in reqs] == contexts
     assert all(r.due_s is None for r in reqs)
-    step = work.step_weight_bytes(m) + sum(contexts) * \
-        work.kv_bytes_per_token(m)
+    step = ARCH.step_weight_bytes(m) + sum(contexts) * \
+        ARCH.kv_bytes_per_token(m)
     budget = math.ceil((RUN_S + TRAFFIC["budget"]["margin_s"]) * 819e9
                        / step)
     assert all(r.max_new_tokens == budget for r in reqs)
@@ -45,7 +45,7 @@ def test_closed_batch_sizes(config, contexts):
     # one session more would not fit
     more = generator.quantile_lengths(TRAFFIC["context_tokens"],
                                       len(contexts) + 1)
-    b2 = CLOSED.session_budget(TRAFFIC, m, more, RUN_S)
+    b2 = CLOSED.session_budget(TRAFFIC, ARCH, m, more, RUN_S)
     assert sum(-(-(c + b2 + e["decode_horizon"] + 1) // e["page_size"])
                for c in more) > e["n_pages"]
 

@@ -4,10 +4,10 @@ with the timed path broken underneath (``bench/faults.py``) is not."""
 import numpy as np
 import pytest
 
+import cell
 import faults
 import reference
 import tiny
-import weights
 
 
 def test_sound_run_is_correct():
@@ -42,9 +42,10 @@ def test_control_fails_where_the_program_passes(seed):
     lies well past the bf16 program's widest gap at the same size."""
     res, rec = tiny.run(tiny.make(limit=1e9, dtype="bfloat16"), seed=seed)
     program = res["check"]["max_logit_gap"]["value"]
-    m = weights.dims(tiny.config("bfloat16"), 16)
-    params = weights.make(m, seed, "bfloat16")
+    arch = cell.arch(rec["arch"])
+    m = arch.dims(tiny.config("bfloat16"), 16)
+    params = arch.make(m, seed, "bfloat16")
     worst = max(float(reference.served_gaps(
-        params, m, rec["prompts"][rid], np.asarray(served),
+        arch, params, m, rec["prompts"][rid], np.asarray(served),
         control=True).max()) for rid, served in rec["served"].items())
     assert worst >= 3 * program, (worst, program)

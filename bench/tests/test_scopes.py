@@ -112,16 +112,17 @@ def test_live_frac_of_a_tiny_window():
     share of the paged grid lies in (0, 1]."""
     import harness
     import tiny
-    import weights
     from repro.serving import DecodeEngine
     c, seed = tiny.make(), 7
     cf = c.config
     cfg = cell.model_config(cf)
-    m = weights.dims(cf, cfg.vocab_multiple)
+    arch = cell.arch_of(cf)
+    m = arch.dims(cf, cfg.vocab_multiple)
     kind = cell.traffic_kind(c.traffic["kind"])
     eng = DecodeEngine(cfg, cell.engine_config(cf),
-                       params=weights.make(m, seed, cf["torch_dtype"]))
-    reqs = kind.requests(c.traffic, m, cf["engine"], c.run_seconds, seed)
+                       params=arch.make(m, seed, cf["torch_dtype"]))
+    reqs = kind.requests(c.traffic, arch, m, cf["engine"], c.run_seconds,
+                         seed)
     h = harness.Run(eng)
     kind.setup(h, reqs)
     t = eng.timing

@@ -89,3 +89,26 @@ def test_hlo_names_containers_left_out():
     assert [n for n, _ in r["device_ops"]] == [
         "closed_call.33 custom-call tpu_custom_call",
         "copy.83 copy bf16[24,3072,16,8,128]"]
+
+
+def test_dense_kernels_credit_the_named_call_alone():
+    """Two Mosaic custom calls in one window: the dense block's KERNELS
+    credits the paged kernel's, by its name, and neither the other kernel
+    nor an op that reads the paged kernel's result."""
+    import cell
+    kernels = cell.arch("dense_swiglu").KERNELS
+    paged = ("%paged_attention.11 = (f32[1,16,16,128]{3,2,1,0}) "
+             "custom-call(s32[16,2048]{1,0} %fusion.166), "
+             "custom_call_target=\"tpu_custom_call\"")
+    other = ("%other_kernel.3 = (bf16[16,2048]{1,0}) custom-call("
+             "bf16[16,2048]{1,0} %fusion.170), "
+             "custom_call_target=\"tpu_custom_call\"")
+    reader = ("%fusion.171 = bf16[16,2048]{1,0} fusion(f32[1,16,16,128]"
+              "{3,2,1,0} %paged_attention.11), kind=kLoop")
+    device = {"/device:TPU:0": [(paged, 10 * MS, 30 * MS),
+                                (other, 40 * MS, 55 * MS),
+                                (reader, 60 * MS, 62 * MS)]}
+    host = [("bench.window", 0, 100 * MS)]
+    r = tracing.reduce(device, host, kernels)
+    assert r["kernel_s"] == {"paged_attn": pytest.approx(0.020)}
+    assert r["busy_s"] == pytest.approx(0.037)

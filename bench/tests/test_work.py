@@ -4,14 +4,14 @@ import math
 import pytest
 
 import cell
-import weights
-import work
+
+work = cell.arch("dense_swiglu")
 reader = cell.metric_reader
 
 
 @pytest.fixture(params=["internlm2-1.8b", "qwen1.5-7b-8L"])
 def dims(request):
-    return weights.dims(cell.config_file(request.param))
+    return work.dims(cell.config_file(request.param))
 
 
 # hand counts: (per-layer matmul params, KV bytes per token, decode FLOPs
@@ -37,6 +37,9 @@ def test_hand_counts(dims):
     assert work.decode_flops(dims, 1000) == dec
     assert work.decode_attention(dims, 1000) == attn
     assert work.step_weight_bytes(dims) == wb
+    assert work.kernel_work(dims, 1000) == {"paged_attn": attn}
+    assert work.step_bytes(dims, [1000, 24]) == wb + 1024 * kv
+    assert work.session_pages(dims, {"page_size": 16}, 1000) == 63
 
 
 def test_prefill_counts_causal_half(dims):
@@ -48,7 +51,7 @@ def test_prefill_counts_causal_half(dims):
 
 
 def _rec(dims, **kw):
-    rec = {"dims": dims, "window_s": 2.0,
+    rec = {"arch": "dense_swiglu", "dims": dims, "window_s": 2.0,
            "peaks": {"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9},
            "requests": [{"prompt_len": 100, "out_start": 5, "out_end": 8}],
            "trace": {"kernel_s": {"paged_attn": 0.01}, "busy_s": 1.5,

@@ -8,7 +8,8 @@ import harness
 
 
 def config(dtype="float32"):
-    return {"name": "tiny", "program_arch": "internlm2-1.8b",
+    return {"name": "tiny", "bench_arch": "dense_swiglu",
+            "program_arch": "internlm2-1.8b",
             "program_full": False, "hidden_size": 64,
             "intermediate_size": 128, "num_attention_heads": 4,
             "num_key_value_heads": 2, "num_hidden_layers": 2,
