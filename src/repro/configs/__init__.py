@@ -19,6 +19,8 @@ from repro.configs import zamba2_1_2b    # noqa: F401
 from repro.configs import qwen1_5_7b     # noqa: F401
 from repro.configs import qwen1_5_14b    # noqa: F401
 from repro.configs import qwen1_5_72b    # noqa: F401
+# served as one chip's share of an expert-parallel deployment
+from repro.configs import mellum2_12b_a2_5b  # noqa: F401
 
 ASSIGNED = (
     "llama3.2-1b", "internlm2-1.8b", "yi-34b", "gemma3-27b", "xlstm-350m",

@@ -41,10 +41,21 @@ class ModelConfig:
     qk_norm: bool = False
     rope_kind: str = "rope"          # rope | mrope | none (learned/sinusoidal)
     rope_theta: float = 10_000.0
+    # --- YaRN (HF ``rope_type: yarn``) on the full ("attn") layers; window
+    # ("local") layers keep plain rope at the same theta. 0 = no YaRN ---
+    yarn_factor: float = 0.0
+    yarn_original_max: int = 0       # original_max_position_embeddings
+    yarn_attention_factor: float = 1.0   # scales cos and sin
     # --- MoE ---
     n_experts: int = 0
     moe_top_k: int = 0
     capacity_factor: float = 1.25
+    # one chip's share of an expert-parallel layer: it holds experts
+    # [moe_held_first, moe_held_first + moe_held) and routes over all
+    # n_experts with no capacity (models/moe.py ``moe_held``); 0 = all, with
+    # GShard capacity
+    moe_held: int = 0
+    moe_held_first: int = 0
     # --- SSM ---
     ssm_state: int = 0               # N (state size per head)
     ssm_expand: int = 2              # d_inner = ssm_expand * d_model
@@ -93,6 +104,11 @@ class ModelConfig:
         return self.n_experts > 0
 
     @property
+    def held_experts(self) -> int:
+        """Experts whose weights this chip holds."""
+        return self.moe_held or self.n_experts
+
+    @property
     def is_attention_free(self) -> bool:
         return not any(k in ("attn", "local") for k in self.block_kinds())
 
@@ -115,7 +131,7 @@ class ModelConfig:
                 n += self.q_dim * self.d_model                      # proj
                 if self.is_moe:
                     n += self.d_model * self.n_experts              # router
-                    n += self.n_experts * 3 * self.d_model * self.d_ff
+                    n += self.held_experts * 3 * self.d_model * self.d_ff
                 elif self.d_ff:
                     mult = 3 if self.act in ("swiglu", "geglu") else 2
                     n += mult * self.d_model * self.d_ff
@@ -142,7 +158,7 @@ class ModelConfig:
             return self.param_count()
         full = self.param_count()
         moe_layers = sum(1 for k in self.block_kinds() if k in ("attn", "local"))
-        all_exp = moe_layers * self.n_experts * 3 * self.d_model * self.d_ff
+        all_exp = moe_layers * self.held_experts * 3 * self.d_model * self.d_ff
         act_exp = moe_layers * self.moe_top_k * 3 * self.d_model * self.d_ff
         return full - all_exp + act_exp
 
@@ -230,6 +246,10 @@ def reduced(cfg: ModelConfig, *, layers: int | None = None) -> ModelConfig:
     )
     if cfg.is_moe:
         kw.update(n_experts=4, moe_top_k=2)
+    if cfg.moe_held:                 # a share that is not the whole layer
+        kw.update(n_experts=8, moe_top_k=4, moe_held=2, moe_held_first=2)
+    if cfg.yarn_factor:              # interpolation past a short original
+        kw.update(yarn_original_max=min(cfg.yarn_original_max, 64))
     if cfg.ssm_state:
         kw.update(ssm_state=8, ssm_head_dim=16)
     if cfg.enc_layers:

@@ -70,10 +70,12 @@ def itpp_decode_attention_shard(q, k_new, v_new, pool_k, pool_v, block_table,
     current token; new_page/new_off [B] global write target; ``window`` may
     be a traced scalar (0 = full attention).
 
-    ``cond_window``: for mixed local:global stacks (gemma3), the per-layer
-    traced ``window`` selects between two widths via lax.cond — windowed
-    layers touch only the pages overlapping the (static-size) window
-    instead of the full context (EXPERIMENTS.md §Perf H3).
+    ``cond_window``: for mixed local:global stacks (gemma3, mellum2), the
+    per-layer traced ``window`` selects between two widths via lax.cond —
+    windowed layers touch only the pages overlapping the (static-size)
+    window instead of the full context (EXPERIMENTS.md §Perf H3). With the
+    Pallas kernel the windowed branch's call is ``paged_attention_window``
+    and the full branch's ``paged_attention``.
 
     ``kernels``: when it resolves to ``use_pallas``, the shard-local compute
     is ``kernels.paged_attention.paged_attention_partials`` — K/V pages

@@ -28,7 +28,7 @@ from jax.sharding import AbstractMesh
 from jax.sharding import PartitionSpec as P
 
 from repro.core.itpp import ItppSpec, make_itpp_attention
-from repro.models.model import Runtime
+from repro.models.model import Runtime, mixed_window
 from repro.models import moe as MOE
 
 STACKED_KEYS = {"layers", "enc", "dec", "mamba", "mlstm", "slstm"}
@@ -235,9 +235,7 @@ class Plan:
             rt.ring_width = pool_spec.max_pages_per_req if pool_spec.ring else 0
             if mode == "decode":
                 spec = self.itpp_spec(parallel.page_size)
-                kinds = set(cfg.block_kinds())
-                mixed = "local" in kinds and "attn" in kinds
-                rt.cond_window = cfg.sliding_window if mixed else 0
+                rt.cond_window = mixed_window(cfg)
                 rt.itpp = make_itpp_attention(
                     self.mesh, spec,
                     max_pages_per_req=pool_spec.max_pages_per_req,

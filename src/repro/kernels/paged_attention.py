@@ -44,7 +44,8 @@ Feature matrix (mirrors the gather-then-dense reference semantics):
                      in-kernel from ``ctx_len``,
   * ``windowed_slice`` the cond_window trick: the caller passes only the
                      table slots overlapping the window; slot ``j`` maps to
-                     virtual page ``max(ctx-w,0)//page + j``,
+                     virtual page ``max(ctx-w,0)//page + j``; this call is
+                     named ``paged_attention_window``,
   * GQA ``G>=1``     q tile is [KVH*G, D]; a row scores only its own
                      head's columns; K/V never repeated.
 """
@@ -234,7 +235,9 @@ def paged_attention_partials(q, k_pages, v_pages, block_tables, ctx_lens, *,
             jax.ShapeDtypeStruct((S, B, R, 1), jnp.float32),
         ],
         interpret=resolve_interpret(interpret),
-        name="paged_attention",
+        # the name its custom call and device-trace events carry: a window
+        # layer's bounded slice apart from the full table
+        name="paged_attention_window" if windowed_slice else "paged_attention",
     )(bt, ctx_lens.astype(jnp.int32), w_arr, q.reshape(B, R, D), k_pages,
       v_pages)
     return (o.reshape(S, B, KVH, G, D), l.reshape(S, B, KVH, G),

@@ -68,11 +68,36 @@ def rope_freqs(d_head: int, theta: float) -> np.ndarray:
     return 1.0 / (theta ** (np.arange(0, d_head // 2, dtype=np.float32) * 2 / d_head))
 
 
-def rope_cos_sin(positions: jax.Array, d_head: int, theta: float):
-    """positions [...]->(cos,sin) of shape [..., d_head/2]."""
-    inv = jnp.asarray(rope_freqs(d_head, theta))
+# YaRN's correction range in turns over the original context: HF's
+# defaults, and the only values a served config uses
+YARN_BETA_FAST, YARN_BETA_SLOW = 32.0, 1.0
+
+
+def yarn_freqs(d_head: int, theta: float, factor: float,
+               original_max: int) -> np.ndarray:
+    """YaRN's inverse frequencies, as HF ``_compute_yarn_parameters`` makes
+    them: each frequency blends the interpolated (``/ factor``) and the
+    extrapolated (plain) one by a linear ramp over the dimensions between
+    those that turn ``YARN_BETA_FAST`` and ``YARN_BETA_SLOW`` times over
+    ``original_max`` positions (floored and ceiled, as HF truncates)."""
+    def dim_of(turns):
+        return (d_head * math.log(original_max / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+    lo = max(math.floor(dim_of(YARN_BETA_FAST)), 0)
+    hi = min(math.ceil(dim_of(YARN_BETA_SLOW)), d_head - 1)
+    ramp = np.clip((np.arange(d_head // 2, dtype=np.float32) - lo)
+                   / (hi - lo if hi > lo else 0.001), 0, 1)
+    plain = rope_freqs(d_head, theta)
+    return (plain / factor * ramp + plain * (1 - ramp)).astype(np.float32)
+
+
+def rope_cos_sin(positions: jax.Array, d_head: int, theta: float,
+                 inv: np.ndarray | None = None, scale: float = 1.0):
+    """positions [...]->(cos,sin) of shape [..., d_head/2]; ``inv`` replaces
+    the plain frequencies and ``scale`` multiplies both (YaRN)."""
+    inv = jnp.asarray(rope_freqs(d_head, theta) if inv is None else inv)
     ang = positions.astype(jnp.float32)[..., None] * inv
-    return jnp.cos(ang), jnp.sin(ang)
+    return jnp.cos(ang) * scale, jnp.sin(ang) * scale
 
 
 def mrope_cos_sin(positions: jax.Array, d_head: int, theta: float,

@@ -55,6 +55,8 @@ class Runtime:
     cond_window: int = 0                             # windowed-bound lax.cond
 
     def moe_apply(self, p, cfg, x):
+        if cfg.moe_held:
+            return MOE.moe_held(p, cfg, x)
         if self.moe is not None:
             return self.moe(p, cfg, x)
         return MOE.moe_local(p, cfg, x)
@@ -81,6 +83,14 @@ class Runtime:
 DEFAULT_RT = Runtime()
 
 
+def mixed_window(cfg) -> int:
+    """``Runtime.cond_window`` for ``cfg``: its window where the stack mixes
+    window and full layers, so that decode attention on a window layer reads
+    only the pages its window spans; else 0."""
+    kinds = set(cfg.block_kinds())
+    return cfg.sliding_window if {"local", "attn"} <= kinds else 0
+
+
 # ---------------------------------------------------------------------------
 # per-kind layer init
 # ---------------------------------------------------------------------------
@@ -97,7 +107,7 @@ def _init_attn_layer(key, cfg, dtype, *, cross: bool = False,
         p["ln2"] = jnp.zeros((cfg.d_model,), dtype)
         if cfg.is_moe:
             p["moe"] = MOE.init_moe(ks[2], cfg, dtype,
-                                    n_virtual=moe_virtual or cfg.n_experts)
+                                    n_virtual=moe_virtual or None)
         else:
             p["mlp"] = L.init_mlp(ks[3], cfg.d_model, cfg.d_ff, cfg.act, dtype)
     return p
@@ -166,13 +176,32 @@ def param_count_actual(params) -> int:
 # ---------------------------------------------------------------------------
 
 def _cos_sin(cfg, positions):
-    """positions [B,S] (rope) or [3,B,S] (mrope) -> cos/sin [B,S,dh/2]."""
+    """positions [B,S] (rope) or [3,B,S] (mrope) -> cos/sin [B,S,dh/2].
+    With YaRN, a pair of such tables, the window layers' and the full
+    layers'; ``_layer_cs`` picks a layer's."""
     if cfg.rope_kind == "none":
         return None
     if cfg.rope_kind == "mrope":
         return L.mrope_cos_sin(positions, cfg.d_head, cfg.rope_theta,
                                cfg.mrope_sections)
-    return L.rope_cos_sin(positions, cfg.d_head, cfg.rope_theta)
+    plain = L.rope_cos_sin(positions, cfg.d_head, cfg.rope_theta)
+    if not cfg.yarn_factor:
+        return plain
+    inv = L.yarn_freqs(cfg.d_head, cfg.rope_theta, cfg.yarn_factor,
+                       cfg.yarn_original_max)
+    return plain, L.rope_cos_sin(positions, cfg.d_head, cfg.rope_theta,
+                                 inv, cfg.yarn_attention_factor)
+
+
+def _layer_cs(cfg, cs, window):
+    """One layer's rotary tables from ``_cos_sin``'s: with YaRN, plain rope
+    where the layer's (traced) ``window`` is set, YaRN's on a full layer.
+    Without YaRN the tables pass through, and the program is unchanged."""
+    if cs is None or not cfg.yarn_factor:
+        return cs
+    plain, yarn = cs
+    on = jnp.asarray(window) > 0
+    return tuple(jnp.where(on, a, b) for a, b in zip(plain, yarn))
 
 
 def default_positions(cfg, B, S, offset=0):
@@ -191,6 +220,7 @@ def _attn_mlp_block(p, cfg, x, cs, window, rt: Runtime, *,
     B, S, D = x.shape
     h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
     q, k, v = L.qkv_project(p["attn"], cfg, h)
+    cs = _layer_cs(cfg, cs, window)
     if cs is not None:
         q = L.apply_rope(q, *cs)
         k = L.apply_rope(k, *cs)
@@ -397,6 +427,7 @@ def _attn_block_decode(p, cfg, x, cs, window, pool_k, pool_v, bt, ctx,
     with jax.named_scope("attn"):
         h = L.rms_norm(x[:, None, :], p["ln1"], cfg.norm_eps)
         q, k, v = L.qkv_project(p["attn"], cfg, h)          # [B,1,H,dh]
+        cs = _layer_cs(cfg, cs, window)
         if cs is not None:
             q = L.apply_rope(q, *cs)
             k = L.apply_rope(k, *cs)
@@ -807,9 +838,10 @@ def decode_verify(cfg, params, state, tokens, proposals, qlogits, bt, ctx,
         lp, w, pkl, pvl = xs
         hn = L.rms_norm(h, lp["ln1"], cfg.norm_eps)
         q, k, v = L.qkv_project(lp["attn"], cfg, hn)      # [B, C, H, dh]
-        if cs is not None:
-            q = L.apply_rope(q, *cs)
-            k = L.apply_rope(k, *cs)
+        cl = _layer_cs(cfg, cs, w)
+        if cl is not None:
+            q = L.apply_rope(q, *cl)
+            k = L.apply_rope(k, *cl)
         pkl, pvl = write_prefill(pkl, pvl, k, v, bt, ctx_start=start,
                                  valid_len=valid_len)
         grp = cfg.n_heads // cfg.n_kv_heads
@@ -943,9 +975,10 @@ def prefill(cfg, params, state, tokens, bt, *, positions=None,
     def attn_prefill_block(lp, h, w, pkl, pvl, cross=None):
         hn = L.rms_norm(h, lp["ln1"], cfg.norm_eps)
         q, k, v = L.qkv_project(lp["attn"], cfg, hn)
-        if cs is not None:
-            q = L.apply_rope(q, *cs)
-            k = L.apply_rope(k, *cs)
+        cl = _layer_cs(cfg, cs, w)
+        if cl is not None:
+            q = L.apply_rope(q, *cl)
+            k = L.apply_rope(k, *cl)
         if rt.write_pool is not None:
             pkl, pvl = rt.write_pool(pkl, pvl, k, v, bt)
         elif rt.ring_width:
@@ -1078,9 +1111,10 @@ def prefill_chunk(cfg, params, state, tokens, bt, ctx_start, *,
     def chunk_attn_block(lp, h, w, pkl, pvl, cross=None):
         hn = L.rms_norm(h, lp["ln1"], cfg.norm_eps)
         q, k, v = L.qkv_project(lp["attn"], cfg, hn)
-        if cs is not None:
-            q = L.apply_rope(q, *cs)
-            k = L.apply_rope(k, *cs)
+        cl = _layer_cs(cfg, cs, w)
+        if cl is not None:
+            q = L.apply_rope(q, *cl)
+            k = L.apply_rope(k, *cl)
         pkl, pvl = write_prefill(pkl, pvl, k, v, bt, ctx_start=start,
                                  valid_len=valid_len)
         kf, vf = gather_kv(pkl, pvl, bt)        # [B, maxp*page, KVH, D]

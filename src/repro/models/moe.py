@@ -16,6 +16,12 @@ Two execution paths share the routing math:
 Router math is fp32. Capacity per real expert follows GShard:
 ``C = ceil(T * top_k * capacity_factor / E)``; overflow tokens keep only the
 residual path.
+
+A third, ``moe_held``, is one chip's share of an expert-parallel layer
+(``cfg.moe_held``): it holds a run of whole experts, routes over all of
+them and computes its own experts' part of each token's output, with no
+capacity, so no token is dropped and a token's output does not depend on
+the others in its batch.
 """
 from __future__ import annotations
 
@@ -30,13 +36,13 @@ F32 = jnp.float32
 
 
 def init_moe(key, cfg, dtype, n_virtual: int | None = None):
-    E, D, ff = cfg.n_experts, cfg.d_model, cfg.d_ff
+    E, D, ff = cfg.held_experts, cfg.d_model, cfg.d_ff
     V = n_virtual or E
     v = V // E
     assert v * E == V and ff % max(v, 1) == 0, (E, V, ff)
     ffv = ff // v
     ks = jax.random.split(key, 4)
-    p = {"router": init_dense(ks[0], D, E, dtype, scale=0.02),
+    p = {"router": init_dense(ks[0], D, cfg.n_experts, dtype, scale=0.02),
          "w1": jax.vmap(lambda k: init_dense(k, D, ffv, dtype))(
              jax.random.split(ks[1], V)),
          "w2": jax.vmap(lambda k: init_dense(k, ffv, D, dtype,
@@ -153,3 +159,36 @@ def moe_ep(p_local, cfg, x_loc, axis: str, n_shards: int):
     summed = back.reshape(E, v, C, D).sum(1)
     y = combine(summed, top_p, top_e, slot, keep)
     return y, aux
+
+
+def moe_held(p, cfg, x):
+    """x [B,S,D] -> (y, aux 0): the part of each token's output that the
+    held experts ``[moe_held_first, moe_held_first + moe_held)`` give.
+
+    The router's logits come out of the matmul in float32; each token keeps
+    its top ``moe_top_k`` of all ``n_experts`` and renormalises their
+    weights over them, as a layer that holds every expert would. Each held
+    expert then runs over every token, weighted by the token's weight on it
+    (0 where the token did not choose it): no capacity, so the output of a
+    token is the same alone as in any batch. It reads each held expert's
+    weights once a call, whichever tokens chose it."""
+    B, S, D = x.shape
+    xt = x.reshape(-1, D)
+    logits = jax.lax.dot_general(xt, p["router"], (((1,), (0,)), ((), ())),
+                                 preferred_element_type=F32)
+    top_p, top_e = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),
+                                 cfg.moe_top_k)
+    top_p = top_p / top_p.sum(-1, keepdims=True)
+    n = p["w1"].shape[0]
+    held = cfg.moe_held_first + jnp.arange(n)
+    gate = jnp.where(top_e == held[:, None, None], top_p,
+                     0.0).sum(-1)                              # [held, T]
+    # every held expert over every token: a matmul batched over the held
+    # experts, so each expert's weights stream once as they are stored
+    xe = jnp.broadcast_to(xt, (n,) + xt.shape)
+    h = activate(_emm("etd,edf->etf", xe, p["w1"]), cfg.act)
+    if "w3" in p:
+        h = h * _emm("etd,edf->etf", xe, p["w3"])
+    h = (h * gate[:, :, None]).astype(x.dtype)
+    y = _emm("etf,efd->td", h, p["w2"]).astype(x.dtype)
+    return y.reshape(B, S, D), jnp.float32(0)

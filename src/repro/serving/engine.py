@@ -304,7 +304,8 @@ class DecodeEngine:
             rt = MDL.Runtime(kernels=KernelConfig(
                 use_pallas=ecfg.use_pallas,
                 interpret=ecfg.kernel_interpret,
-                n_splits=ecfg.kernel_splits))
+                n_splits=ecfg.kernel_splits),
+                cond_window=MDL.mixed_window(cfg))
         self.rt = rt
         # draft/target compat is validated BEFORE any params are allocated:
         # a tokenizer (vocab) mismatch must fail here, loudly, not as a

@@ -89,6 +89,32 @@ def test_paged_attention_kernel_is_named(one_chip):
     assert "/paged_attention/pallas_call" in calls[0]
 
 
+def test_paged_attention_window_kernel_is_named(one_chip):
+    """The windowed slice that a window layer's decode takes
+    (``cond_window``) is a custom call of its own name,
+    ``paged_attention_window``, so a device trace times it apart from the
+    full table's ``paged_attention``."""
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = [sds((B, KVH, G, D), jnp.bfloat16),
+            sds((P, PAGE, KVH, D), jnp.bfloat16),
+            sds((P, PAGE, KVH, D), jnp.bfloat16),
+            sds((B, 66), jnp.int32), sds((B,), jnp.int32),
+            sds((B,), jnp.int32)]
+
+    def f(q, k, v, bt, ctx, window):
+        return paged_attention_partials(q, k, v, bt, ctx, window=window,
+                                        windowed_slice=True, interpret=False)
+
+    text = jax.jit(f).lower(*args).compile().as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 1
+    assert calls[0].split(" = ")[0].split()[-1].startswith(
+        "%paged_attention_window")
+    assert "/paged_attention_window/pallas_call" in calls[0]
+
+
 # an HLO instruction that produces an array: name, shape, opcode, callee
 _INSTR = re.compile(r"\s*(?:ROOT )?%(\S+) = ([a-z0-9]+\[[0-9,]*\])\S* "
                     r"([\w-]+)\((?:.*calls=%([\w.-]+))?")
@@ -125,18 +151,54 @@ def test_decode_horizon_writes_pool_in_place(one_chip):
     that a served pool never has."""
     from dataclasses import replace
     from repro.configs import get_config
+
+    cfg = replace(get_config("internlm2-1.8b"), n_layers=2,
+                  d_model=512, d_ff=1024, vocab_size=512)
+    assert (cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads, cfg.d_head) \
+        == (KVH, G, D)
+    text = _horizon_hlo(one_chip, cfg, cond_window=0, pages=1024, width=8)
+    _assert_pool_in_place(text, cfg, pages=1024)
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert calls
+    assert all("%paged_attention" in c.split(" = ")[0] for c in calls)
+    assert not any("paged_attention_window" in c for c in calls)
+
+
+def test_mixed_stack_decode_horizon_takes_both_kernels_in_place(one_chip):
+    """The fused decode horizon of a stack that mixes window and full
+    layers (mellum2's block: GQA 32/4, YaRN full layers, held experts),
+    with the engine's ``cond_window``: the full branch's
+    ``paged_attention`` and the window branch's ``paged_attention_window``
+    both compile, each reading the stacked pool at its layer's offset, and
+    the token is still added to the pool in place."""
+    from dataclasses import replace
+    from repro.configs import get_config
+    from repro.models import model as MDL
+
+    cfg = replace(get_config("mellum2-12b-a2.5b"), n_layers=4, d_model=512,
+                  vocab_size=512, n_experts=8, moe_top_k=4, moe_held=2,
+                  sliding_window=64)
+    assert MDL.mixed_window(cfg) == 64
+    text = _horizon_hlo(one_chip, cfg, cond_window=64, pages=1024,
+                        width=32)
+    _assert_pool_in_place(text, cfg, pages=1024)
+    names = {c.split(" = ")[0].split()[-1].split(".")[0]
+             for c in text.splitlines() if "tpu_custom_call" in c}
+    assert names == {"%paged_attention", "%paged_attention_window"}
+
+
+def _horizon_hlo(one_chip, cfg, *, cond_window, pages, width, slots=4):
+    """The compiled HLO text of ``decode_multi`` under jit (state donated,
+    the Pallas kernel) for ``cfg`` over a ``pages``-page stacked pool."""
     from repro.core.paged_kv import PoolSpec
     from repro.models import model as MDL
     from repro.serving.sampling import make_scan_sampler
     from repro.kernels.backend import KernelConfig
 
-    n_layers, pages, slots, width = 2, 1024, 4, 8
-    cfg = replace(get_config("internlm2-1.8b"), n_layers=n_layers,
-                  d_model=512, d_ff=1024, vocab_size=512)
-    assert (cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads, cfg.d_head) \
-        == (KVH, G, D)
-    rt = MDL.Runtime(kernels=KernelConfig(use_pallas=True, interpret=False))
-    spec = PoolSpec(n_layers, pages, PAGE, KVH, D, width)
+    rt = MDL.Runtime(kernels=KernelConfig(use_pallas=True, interpret=False),
+                     cond_window=cond_window)
+    spec = PoolSpec(cfg.n_layers, pages, PAGE, cfg.n_kv_heads, cfg.d_head,
+                    width)
 
     def horizon(params, state, tokens, bt, ctx, rem, allow, key):
         return MDL.decode_multi(
@@ -155,10 +217,16 @@ def test_decode_horizon_writes_pool_in_place(one_chip):
         *(jax.ShapeDtypeStruct(s, jnp.int32)
           for s in ((slots,), (slots, width), (slots,), (slots,), (slots,))),
         jax.eval_shape(lambda: jax.random.PRNGKey(0))))
-    text = jax.jit(horizon, donate_argnums=(1, 2, 4, 5, 7)).lower(
+    return jax.jit(horizon, donate_argnums=(1, 2, 4, 5, 7)).lower(
         *args).compile().as_text()
 
-    tail = f"{PAGE},{KVH},{D}]"
+
+def _assert_pool_in_place(text, cfg, *, pages):
+    """No copy, dynamic-slice, dynamic-update-slice or other fusion
+    produces a value of the pool's shape, whole, one layer's or flat: only
+    parameters, tuple elements, bitcasts and the token's scatters do."""
+    n_layers = cfg.n_layers
+    tail = f"{PAGE},{cfg.n_kv_heads},{cfg.d_head}]"
     pool_shapes = {f"bf16[{lead}{tail}" for lead in (
         f"{n_layers},{pages},", f"{pages},", f"1,{pages},",
         f"{n_layers * pages},")}
@@ -175,6 +243,3 @@ def test_decode_horizon_writes_pool_in_place(one_chip):
     assert not moved, moved
     assert any(op == "scatter" and shape in pool_shapes
                for instrs in comps.values() for _, shape, op, _ in instrs)
-    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
-    assert calls
-    assert all("%paged_attention" in c.split(" = ")[0] for c in calls)
